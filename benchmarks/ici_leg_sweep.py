@@ -19,9 +19,9 @@ rows are ``obs.bench_record`` dicts carrying the ``knobs`` stamp).
 
 Bridge-level with the parent-package shim (no jax import in the
 ranks), so it runs in ANY container; every row names the leg backend
-it actually measured (``leg_backend``: ``"pallas"`` on a TPU slice
-with jax >= 0.6, ``"numpy"`` — the bit-identical twin, Python-rate —
-elsewhere).  Numbers from the numpy twin bound the SCHEDULE (frames,
+it actually measured (``leg_backend``: ``"pallas"`` where the rank
+runs jax on a TPU holding the island's chips, ``"numpy"`` — the
+bit-identical reference, Python-rate — elsewhere).  Numbers from the numpy twin bound the SCHEDULE (frames,
 phases, association), not the TPU kernel.
 
 Timing is the raw-transport shape of ``allreduce_sweep.py --world``:

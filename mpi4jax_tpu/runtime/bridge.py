@@ -18,6 +18,7 @@ mpi_xla_bridge.pyx:67-91); peers then fail on their sockets and exit too.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import sys
@@ -31,67 +32,70 @@ _REPO_ROOT = os.path.dirname(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
 _SO_PATH = os.path.join(os.path.dirname(__file__), "_native", "libtpucomm.so")
-_SRC = os.path.join(_REPO_ROOT, "native", "tpucomm.cc")
-_FFI_SRC = os.path.join(_REPO_ROOT, "native", "tpucomm_ffi.cc")
+# the hash of the sources the library was built from, beside the library
+# (native/Makefile writes the same stamp: sha256 of the three files' bytes
+# concatenated in this order)
+_STAMP_PATH = _SO_PATH + ".sha256"
+_NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
+_SRC = os.path.join(_NATIVE_DIR, "tpucomm.cc")
+_FFI_SRC = os.path.join(_NATIVE_DIR, "tpucomm_ffi.cc")
+_SOURCES = (_SRC, _FFI_SRC, os.path.join(_NATIVE_DIR, "tpucomm.h"))
 
 _lib: Optional[ctypes.CDLL] = None
 
 
-def _build() -> None:
+def _source_hash() -> str:
+    h = hashlib.sha256()
+    for src in _SOURCES:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _build(digest: str) -> None:
+    """Build transport + XLA FFI handlers from the committed sources;
+    a failed build raises with the compiler's output."""
     # Build to a temp path and atomically rename: concurrent launcher ranks
     # may rebuild simultaneously, and a sibling rank must never CDLL-load a
     # partially written .so.
+    import jax.ffi as _jffi
+
     os.makedirs(os.path.dirname(_SO_PATH), exist_ok=True)
     tmp = f"{_SO_PATH}.tmp.{os.getpid()}"
-    base = [
+    cmd = [
         os.environ.get("CXX", "g++"),
         "-O2", "-std=c++17", "-fPIC", "-Wall", "-pthread", "-shared",
-        "-o", tmp,
+        "-o", tmp, f"-I{_NATIVE_DIR}", f"-I{_jffi.include_dir()}",
+        _SRC, _FFI_SRC,
+        # glibc < 2.34 keeps shm_open in librt (an empty stub after):
+        # without it the link succeeds but dlopen fails with an undefined
+        # symbol
+        "-lrt",
     ]
-    # glibc < 2.34 keeps shm_open in librt (an empty stub after): without
-    # it the link succeeds but dlopen fails with an undefined symbol
-    tail = ["-lrt"]
-    # preferred: transport + XLA FFI handlers (needs jaxlib's bundled
-    # headers); fall back to transport-only — the op layer then routes
-    # through host callbacks instead of native custom calls
     try:
-        if os.path.exists(_FFI_SRC):
-            try:
-                import jax.ffi as _jffi
-
-                native_dir = os.path.dirname(_SRC)
-                subprocess.run(
-                    base
-                    + [f"-I{native_dir}", f"-I{_jffi.include_dir()}",
-                       _SRC, _FFI_SRC] + tail,
-                    check=True, capture_output=True, text=True,
-                )
-                os.replace(tmp, _SO_PATH)
-                return
-            except subprocess.CalledProcessError as e:
-                import warnings
-
-                warnings.warn(
-                    "building the native FFI fast path failed; falling back "
-                    f"to a transport-only build:\n{e.stderr}"
-                )
-            except ImportError:
-                pass
-        subprocess.run(base + [_SRC] + tail, check=True)
+        r = subprocess.run(cmd, capture_output=True, text=True)
+        if r.returncode != 0:
+            raise RuntimeError(
+                f"building the native transport failed "
+                f"({' '.join(cmd)}):\n{r.stderr}"
+            )
         os.replace(tmp, _SO_PATH)
+        with open(f"{_STAMP_PATH}.tmp.{os.getpid()}", "w") as f:
+            f.write(digest + "\n")
+        os.replace(f"{_STAMP_PATH}.tmp.{os.getpid()}", _STAMP_PATH)
     finally:
         if os.path.exists(tmp):
             os.remove(tmp)
 
 
-def _stale() -> bool:
-    if not os.path.exists(_SO_PATH):
+def _stale(digest: str) -> bool:
+    """True unless the library beside us was built from these exact
+    sources (a hash, not mtimes: a copied tree keeps neither)."""
+    try:
+        with open(_STAMP_PATH) as f:
+            return f.read().strip() != digest or not os.path.exists(_SO_PATH)
+    except FileNotFoundError:
         return True
-    so_mtime = os.path.getmtime(_SO_PATH)
-    return any(
-        os.path.exists(src) and os.path.getmtime(src) > so_mtime
-        for src in (_SRC, _FFI_SRC)
-    )
 
 
 def get_lib() -> ctypes.CDLL:
@@ -101,32 +105,15 @@ def get_lib() -> ctypes.CDLL:
     so_path = config.native_lib_override()
     if so_path is not None:
         # explicit library (sanitizer builds, cross-build tests): load it
-        # verbatim — no staleness heuristics, no rebuild
+        # verbatim — no staleness check, no rebuild
         if not os.path.exists(so_path):
             raise RuntimeError(
                 f"MPI4JAX_TPU_NATIVE_LIB={so_path} does not exist"
             )
         return _finish_lib_setup(ctypes.CDLL(so_path))
-    if _stale():
-        if not os.path.exists(_SRC) and not os.path.exists(_SO_PATH):
-            raise RuntimeError(
-                f"native transport missing: no {_SO_PATH} and no source at "
-                f"{_SRC} to build it from"
-            )
-        try:
-            _build()
-        except Exception as e:
-            # git checkouts don't preserve mtimes, so staleness is a
-            # heuristic — a shipped .so must keep working on hosts without
-            # a C++ toolchain
-            if not os.path.exists(_SO_PATH):
-                raise
-            import warnings
-
-            warnings.warn(
-                f"rebuilding stale native transport failed ({e}); using the "
-                f"existing {_SO_PATH}"
-            )
+    digest = _source_hash()
+    if _stale(digest):
+        _build(digest)
     return _finish_lib_setup(ctypes.CDLL(_SO_PATH))
 
 
@@ -990,9 +977,9 @@ def _data_ptr(a: np.ndarray) -> int:
 # in-jit timings (glibc returns big frees to the kernel immediately) —
 # the 16 MiB allreduce measured 0.859 GB/s/rank in-jit vs 0.935 at the
 # transport before reuse.  Safe because callback results are COPIED
-# into the XLA output buffer before the (ordered) callback returns;
-# staged-eager dispatch must NOT use these (device_put may alias the
-# numpy buffer) — the ops layer passes reuse=False there.  Keyed by
+# into the XLA output buffer before the (ordered) callback returns; a
+# caller that hands the result on as its own array passes reuse=False
+# (the default).  Keyed by
 # (comm, op, shape, dtype) so alternating shapes each keep a buffer
 # instead of thrashing one slot; bounded like the descriptor cache
 # (large buffers bound at 16 entries per thread).
@@ -1127,8 +1114,8 @@ def comm_size(handle) -> int:
 # ops ride the batched descriptor entry (one cached struct + one native
 # call) when the loaded .so carries it; ``reuse=True`` additionally
 # reuses the output buffer per (comm, op) — callback-path only (results
-# are copied into XLA buffers before the callback returns; staged-eager
-# dispatch must keep fresh buffers, see _out_cache).
+# are copied into XLA buffers before the callback returns, see
+# _reused_out).
 
 def send(handle, buf: np.ndarray, dest: int, tag: int):
     buf = _contig(buf)

@@ -390,6 +390,31 @@ def _merge_trace(out_path: str, np_: int) -> None:
               flush=True)
 
 
+# libtpu's slice-builder port; one per rank process on a shared host
+_TPU_PROCESS_PORT0 = 8476
+
+
+def tpu_rank_env(platforms: str, slot: int, np_: int) -> dict:
+    """Environment that gives rank ``slot`` its own TPU chip.
+
+    A TPU process claims every chip it can see, so ``np_ > 1`` ranks on
+    one host would each try to claim all of them.  Each rank instead
+    sees one chip (chip ``slot``) as a single-chip slice of its own,
+    with its own libtpu port; several processes then load libtpu, one
+    chip each.  A rank whose chip does not exist fails at libtpu start.
+    One rank, or a platform list without ``tpu``, keeps every chip.
+    """
+    if np_ <= 1 or "tpu" not in platforms.split(","):
+        return {}
+    return {
+        "TPU_VISIBLE_CHIPS": str(slot),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_PORT": str(_TPU_PROCESS_PORT0 + slot),
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
+
+
 def main(argv=None):
     parser = argparse.ArgumentParser(
         prog="python -m mpi4jax_tpu.runtime.launch",
@@ -610,6 +635,7 @@ def main(argv=None):
             env["JAX_PLATFORMS"] = args.platform
         else:
             env.setdefault("JAX_PLATFORMS", "cpu")
+        env.update(tpu_rank_env(env["JAX_PLATFORMS"], slot, args.np))
         p = subprocess.Popen(
             [sys.executable, args.prog, *args.args], env=env,
             stderr=subprocess.PIPE,

@@ -33,11 +33,10 @@ Schedule (phases mirror the native ``hier_allreduce``):
 
 1. intra: allgather the members' payloads over the intra sub-comm and
    fold them with the ring association — the Pallas fused-ring kernel
-   when jax >= 0.6 and enough local devices are present, else its
-   bit-identical numpy twin (``simulate_ring_sum``'s arithmetic; the
-   kernel is verified against it in interpret mode).  Either way the
-   result is EXACTLY ``topo.simulate_hring_sum(..., intra="ring")``'s
-   phase 1;
+   where this process runs jax on a TPU holding the island's chips,
+   else its numpy reference (``simulate_ring_sum``'s arithmetic; the
+   kernel is verified against it).  Either way the result is EXACTLY
+   ``topo.simulate_hring_sum(..., intra="ring")``'s phase 1;
 2. leaders: exact mode forces the flat ``ring``/``rd`` twin of the
    requested hierarchical algorithm over the leaders sub-comm; quant
    mode allgathers the once-packed int8 frames (lossless) and EVERY
@@ -59,6 +58,7 @@ events — zero double-counting either way.
 
 from __future__ import annotations
 
+import sys
 import time
 from typing import Optional
 
@@ -69,38 +69,21 @@ from ..utils import config
 #: native wire codes (native/tpucomm.h) — the leg is f32 SUM only
 _F32, _SUM = 11, 0
 
-_BACKEND: Optional[str] = None
 _RING_CACHE: dict = {}
 _PACK_CACHE: dict = {}
 
 
-def _pallas_ready() -> bool:
-    """Can the fused Pallas kernels actually run here (jax >= 0.6 with
-    the Pallas remote-DMA API importable)?  Resolved once; when False
-    the leg runs its bit-identical numpy twin instead, so bridge-level
-    worlds exercise the same schedule in ANY container."""
-    try:
-        import jax
-
-        parts = []
-        for piece in jax.__version__.split(".")[:3]:
-            parts.append(int("".join(c for c in piece if c.isdigit()) or 0))
-        if tuple(parts) < (0, 6, 0):
-            return False
-        from ..ops import pallas_collectives  # noqa: F401
-
-        return True
-    except Exception:
-        return False
-
-
-def ici_leg_backend() -> str:
-    """``"pallas"`` (fused kernels, interpret mode off-TPU) or
-    ``"numpy"`` (the bit-identical twin) — resolved once per process."""
-    global _BACKEND
-    if _BACKEND is None:
-        _BACKEND = "pallas" if _pallas_ready() else "numpy"
-    return _BACKEND
+def ici_leg_backend(members: int = 1) -> str:
+    """``"pallas"`` where this process runs jax on a TPU with at least
+    ``members`` local chips: the fused kernels run there, compiled.
+    ``"numpy"`` elsewhere: the kernels' bit-identical reference (off the
+    TPU a Pallas kernel would only run interpreted).  Reading the
+    platform never imports jax: a rank that has not imported it holds
+    no chip, and importing it here would claim every chip of the host."""
+    jax = sys.modules.get("jax")
+    if jax is None or jax.default_backend() != "tpu":
+        return "numpy"
+    return "pallas" if jax.local_device_count() >= members else "numpy"
 
 
 def _quant_mod():
@@ -143,14 +126,8 @@ def _ring_sum_pallas(rows: np.ndarray) -> np.ndarray:
 def _island_ring_sum(rows: np.ndarray) -> np.ndarray:
     """(m, count) f32 member rows -> the island sum every member holds,
     with the EXACT ``simulate_ring_sum`` association either way."""
-    if ici_leg_backend() == "pallas":
-        try:
-            import jax
-
-            if len(jax.devices()) >= rows.shape[0]:
-                return _ring_sum_pallas(rows)
-        except Exception:
-            pass
+    if ici_leg_backend(rows.shape[0]) == "pallas":
+        return _ring_sum_pallas(rows)
     return _ring_sum_numpy(rows)
 
 
@@ -172,13 +149,10 @@ def _pack_pallas(island: np.ndarray) -> np.ndarray:
 
 def _pack(island: np.ndarray) -> np.ndarray:
     """The native int8 wire frame of the island sum (scale bytes then
-    codes) — in-kernel when the Pallas backend is live, else the numpy
-    codec reference; bit-identical by (test-enforced) contract."""
+    codes) — in-kernel on a TPU, else the numpy codec reference;
+    bit-identical by (test-enforced) contract."""
     if ici_leg_backend() == "pallas":
-        try:
-            return _pack_pallas(island)
-        except Exception:
-            pass
+        return _pack_pallas(island)
     return _pack_numpy(island)
 
 

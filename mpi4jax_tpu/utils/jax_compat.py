@@ -2,8 +2,8 @@
 
 The reference pins a "latest known good" jax and warns beyond it
 (/root/reference/mpi4jax/_src/jax_compat.py:24-47).  We do the same with a
-much smaller surface: this framework targets jax >= 0.9 (no pre-0.5 shims —
-the reference needed them for jax 0.4.x, we do not).
+much smaller surface: this framework is written for jax 0.9 and carries
+no shims for older releases.
 """
 
 from __future__ import annotations
@@ -12,7 +12,7 @@ import warnings
 
 from . import config
 
-MIN_JAX_VERSION = (0, 6, 0)
+MIN_JAX_VERSION = (0, 9, 0)
 LATEST_TESTED_JAX_VERSION = (0, 9, 0)
 
 
@@ -62,32 +62,3 @@ def vma_check_mode():
         return bool(_jcfg._check_vma.value)
     except Exception:
         return None
-
-
-def bool_state(**kwargs):
-    """``jax._src.config.bool_state`` across jax versions.
-
-    Newer keyword-only flags (``include_in_jit_key``,
-    ``include_in_trace_context``) are dropped when the installed jax
-    predates them, so modules defining config states stay *importable* on
-    older jax — wanted by tooling that runs without compiling anything
-    (``mpi4jax_tpu.analysis`` executes eagerly under ``disable_jit``,
-    where the jit-cache-key flag is moot).  Production use is still
-    gated on MIN_JAX_VERSION by ``check_jax_version``.
-    """
-    from jax._src import config as _jcfg
-
-    kw = dict(kwargs)
-    for _ in range(2):
-        try:
-            return _jcfg.bool_state(**kw)
-        except TypeError as err:
-            dropped = False
-            for opt in ("include_in_trace_context", "include_in_jit_key"):
-                if opt in kw and opt in str(err):
-                    kw.pop(opt)
-                    dropped = True
-                    break
-            if not dropped:
-                raise
-    return _jcfg.bool_state(**kw)

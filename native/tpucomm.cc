@@ -1381,15 +1381,16 @@ void uring_probe() {
                      g_uring_reason);
       return;
     }
-    struct {
-      io_uring_probe p;   /* ops[0] flexible member lands on ops below */
-      io_uring_probe_op ops[64];
-    } pr{};
+    /* io_uring_probe ends in the flexible array ops[]: probe into an
+     * aligned buffer with room for 64 entries behind the header */
+    alignas(io_uring_probe) unsigned char
+        buf[sizeof(io_uring_probe) + 64 * sizeof(io_uring_probe_op)] = {};
+    auto* pr = reinterpret_cast<io_uring_probe*>(buf);
     count_sys();
     if (::syscall(__NR_io_uring_register, probe->fd, IORING_REGISTER_PROBE,
-                  &pr, 64) == 0 &&
-        pr.p.ops_len > kOpSendZc &&
-        (pr.ops[kOpSendZc].flags & IO_URING_OP_SUPPORTED))
+                  pr, 64) == 0 &&
+        pr->ops_len > kOpSendZc &&
+        (pr->ops[kOpSendZc].flags & IO_URING_OP_SUPPORTED))
       g_uring_zc = true;
     g_uring_avail = 1;
   });

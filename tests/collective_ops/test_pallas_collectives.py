@@ -212,9 +212,10 @@ def test_fused_ring_allreduce_grad_is_itself():
 def test_large_allreduce_dispatches_to_fused_ring():
     # the dispatch arm: bandwidth-bound payloads on n > 2 ride the
     # fused kernel, so allreduce_sum must be bit-identical to it there
+    # (the threshold is per shard: each of the 4 holds BIDIR_MIN_ELEMS+8)
     mesh = _mesh()
     rng = np.random.RandomState(23)
-    x = jnp.asarray(rng.randn(4 * 4096 + 8), np.float32)
+    x = jnp.asarray(rng.randn(4 * (pc.BIDIR_MIN_ELEMS + 8)), np.float32)
     via_dispatch = _smap(lambda v: pc.allreduce_sum(v, "x"), mesh)(x)
     direct = _smap(lambda v: pc.fused_ring_allreduce_sum(v, "x"), mesh)(x)
     np.testing.assert_array_equal(np.asarray(via_dispatch),

@@ -85,3 +85,21 @@ def test_pallas_impl_rejects_decomposed_grid():
     model = ShallowWater(grid, (16, 32), SWParams(dx=5e3, dy=5e3))
     with pytest.raises(ValueError, match="1x1 grid"):
         model.step_fn(1, impl="pallas")
+
+
+def test_auto_impl_surfaces_kernel_compile_error(monkeypatch):
+    """impl="auto" on a TPU target picks the fused kernel, and a kernel
+    that fails to build raises: no fall-back to another tile or to the
+    XLA step hides it."""
+    from mpi4jax_tpu.models import _sw_pallas
+    from mpi4jax_tpu.ops import flash
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(flash, "target_platform", lambda: "tpu")
+    monkeypatch.setattr(_sw_pallas, "fused_step", broken)
+    model = _model()
+    state = model.init()
+    with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
+        model.step_fn(4, first=True, impl="auto")(state)

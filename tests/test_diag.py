@@ -13,7 +13,7 @@ def test_diag_cpu_checks():
     env.pop("XLA_FLAGS", None)
     res = subprocess.run(
         [sys.executable, "-m", "mpi4jax_tpu.runtime.diag", "--json",
-         "--port", "45990"],
+         "--port", "25990"],
         capture_output=True, text=True, timeout=240, cwd=REPO, env=env,
     )
     assert res.returncode == 0, res.stdout + res.stderr

@@ -138,3 +138,60 @@ def test_explicit_token_ordering_effect_selection():
         unordered_jaxpr = jax.make_jaxpr(prog)(jnp.zeros(2))
     assert unordered_comm_effect in unordered_jaxpr.effects
     assert comm_effect not in unordered_jaxpr.effects
+
+
+def test_native_build_failure_raises(tmp_path, monkeypatch):
+    # no transport-only fallback and no stale library: a build that
+    # fails raises with the compiler's output and leaves nothing behind
+    from mpi4jax_tpu.runtime import bridge
+
+    so, stamp = tmp_path / "lib.so", tmp_path / "lib.so.sha256"
+    monkeypatch.setattr(bridge, "_SO_PATH", str(so))
+    monkeypatch.setattr(bridge, "_STAMP_PATH", str(stamp))
+    monkeypatch.setenv("CXX", "false")
+    with pytest.raises(RuntimeError, match="building the native transport"):
+        bridge._build(bridge._source_hash())
+    assert list(tmp_path.iterdir()) == []
+
+
+def test_native_staleness_keys_on_source_hash(tmp_path, monkeypatch):
+    # a library is current only when the stamp beside it names the hash
+    # of these sources: a .so copied in from another build is rebuilt
+    from mpi4jax_tpu.runtime import bridge
+
+    so, stamp = tmp_path / "lib.so", tmp_path / "lib.so.sha256"
+    monkeypatch.setattr(bridge, "_SO_PATH", str(so))
+    monkeypatch.setattr(bridge, "_STAMP_PATH", str(stamp))
+    digest = bridge._source_hash()
+    assert bridge._stale(digest)            # nothing built
+    so.write_bytes(b"")
+    assert bridge._stale(digest)            # a library with no stamp
+    stamp.write_text("0" * 64 + "\n")
+    assert bridge._stale(digest)            # built from other sources
+    stamp.write_text(digest + "\n")
+    assert not bridge._stale(digest)
+
+
+@pytest.mark.parametrize("preset", [True, False])
+def test_compile_cache_dir(tmp_path, monkeypatch, preset):
+    # JAX_COMPILATION_CACHE_DIR when set, and nothing else; else one
+    # fixed path in the checkout.  Either way it is exported, so that
+    # launcher ranks share it, and handed to an imported jax
+    import os
+
+    from mpi4jax_tpu.utils import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    if preset:
+        want = str(tmp_path)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = os.path.join(compile_cache._REPO_ROOT, ".jax_cache")
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert compile_cache.use_compile_cache() == want
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert list(tmp_path.iterdir()) == []

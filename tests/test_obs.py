@@ -621,7 +621,7 @@ def test_launch_trace_end_to_end_bridge_level(tmp_path, np_):
     env["MPI4JAX_TPU_DISABLE_SHM"] = "1"  # record real TCP algorithms
     res = subprocess.run(
         [sys.executable, str(REPO / "mpi4jax_tpu/runtime/launch.py"),
-         "-n", str(np_), "--port", "46610", "--trace", str(out),
+         "-n", str(np_), "--port", "26610", "--trace", str(out),
          str(prog)],
         capture_output=True, text=True, timeout=300, env=env, cwd=repo,
     )

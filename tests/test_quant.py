@@ -182,26 +182,6 @@ def test_wire_ref_matches_reference_layout():
             wire, np.concatenate([scales.view(np.int8), codes]))
 
 
-def _pallas_codec_ok():
-    """The in-kernel codec needs the gated jax AND an importable Pallas
-    TPU backend (interpret mode runs it on CPU)."""
-    try:
-        import jax
-
-        parts = []
-        for piece in jax.__version__.split(".")[:3]:
-            parts.append(int("".join(c for c in piece if c.isdigit()) or 0))
-        if tuple(parts) < (0, 6, 0):
-            return False
-        from mpi4jax_tpu.ops import pallas_collectives  # noqa: F401
-
-        return True
-    except Exception:
-        return False
-
-
-@pytest.mark.skipif(not _pallas_codec_ok(),
-                    reason="needs jax >= 0.6 with Pallas")
 @pytest.mark.parametrize("n", [1, 255, 256, 257, 1000, 4096])
 def test_pallas_pack_bit_identical_to_reference(n):
     """The cross-ISA contract extended to the THIRD codec: the Pallas

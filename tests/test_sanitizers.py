@@ -186,7 +186,7 @@ def test_tsan_loopback_pair(shm, uring):
         extra["MPI4JAX_TPU_DISABLE_SHM"] = "1"
     _run_pair(
         so, preload, san,
-        46200 + (os.getpid() + (7 if shm == "on" else 0)
+        26200 + (os.getpid() + (7 if shm == "on" else 0)
                  + (29 if uring == "1" else 0)) % 900,
         extra,
     )
@@ -318,7 +318,7 @@ def test_tsan_progress_engine_three_ranks(shm, uring):
         extra["MPI4JAX_TPU_DISABLE_SHM"] = "1"
     _run_group(
         _ENGINE_RANK_SRC, 3, so, preload, san,
-        48200 + (os.getpid() + (13 if shm == "on" else 0)
+        28200 + (os.getpid() + (13 if shm == "on" else 0)
                  + (31 if uring == "1" else 0)) % 900,
         extra,
     )
@@ -438,7 +438,7 @@ def test_tsan_hier_two_islands_three_ranks(shm):
     _run_group(
         _HIER_RANK_SRC, 3, so, preload,
         {"TSAN_OPTIONS": "exitcode=66 halt_on_error=0"},
-        48500 + (os.getpid() + (17 if shm == "on" else 0)) % 900,
+        28500 + (os.getpid() + (17 if shm == "on" else 0)) % 900,
         extra,
     )
 
@@ -548,7 +548,7 @@ def test_tsan_shrink_under_load_three_ranks(shm):
     _run_group(
         _SHRINK_RANK_SRC, 3, so, preload,
         {"TSAN_OPTIONS": "exitcode=66 halt_on_error=0"},
-        48400 + (os.getpid() + (19 if shm == "on" else 0)) % 900,
+        28400 + (os.getpid() + (19 if shm == "on" else 0)) % 900,
         extra,
     )
 
@@ -648,7 +648,7 @@ def test_tsan_self_heal_reconnect(shm, uring):
     san = {"TSAN_OPTIONS": "exitcode=66 halt_on_error=0"}
     _run_group(
         _HEAL_RANK_SRC, 2, so, preload, san,
-        48700 + (os.getpid() + (23 if shm == "on" else 0)
+        28700 + (os.getpid() + (23 if shm == "on" else 0)
                  + (41 if uring == "1" else 0)) % 400,
         _heal_env(shm, uring, so, preload, san, "tsanheal"),
     )
@@ -666,7 +666,7 @@ def test_asan_self_heal_reconnect(shm, uring):
     }
     _run_group(
         _HEAL_RANK_SRC, 2, so, preload, san,
-        49100 + (os.getpid() + (23 if shm == "on" else 0)
+        29100 + (os.getpid() + (23 if shm == "on" else 0)
                  + (41 if uring == "1" else 0)) % 400,
         _heal_env(shm, uring, so, preload, san, "asanheal"),
     )
@@ -688,7 +688,7 @@ def test_asan_loopback_pair(shm, uring):
         extra["MPI4JAX_TPU_DISABLE_SHM"] = "1"
     _run_pair(
         so, preload, san,
-        47200 + (os.getpid() + (7 if shm == "on" else 0)
+        27200 + (os.getpid() + (7 if shm == "on" else 0)
                  + (37 if uring == "1" else 0)) % 900,
         extra,
     )
@@ -756,6 +756,6 @@ def test_tsan_live_retune_pair(shm):
     san = {"TSAN_OPTIONS": "exitcode=66 halt_on_error=0"}
     _run_group(
         _LIVE_RANK_SRC, 2, so, preload, san,
-        49500 + (os.getpid() + (19 if shm == "on" else 0)) % 400,
+        29500 + (os.getpid() + (19 if shm == "on" else 0)) % 400,
         _live_env(shm, "tsanlive"),
     )

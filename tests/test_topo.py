@@ -448,22 +448,6 @@ def test_stats_without_tier_events_schema_unchanged():
 
 # ---------------- analysis invariance (needs jax) ----------------
 
-def _jax_ok():
-    # the shim above registers a bare package module, so "import
-    # mpi4jax_tpu" succeeding is not enough — the analysis trace needs
-    # the real op layer, which needs the gated jax version
-    try:
-        import jax
-
-        parts = []
-        for piece in jax.__version__.split(".")[:3]:
-            parts.append(int("".join(c for c in piece if c.isdigit()) or 0))
-        return tuple(parts) >= (0, 6, 0)
-    except Exception:
-        return False
-
-
-@pytest.mark.skipif(not _jax_ok(), reason="needs jax >= 0.6")
 def test_hier_algo_keeps_plain_allreduce_schedule_signature():
     """Hierarchical routing is INVISIBLE to the static verifier: a
     forced hring allreduce extracts the same per-rank schedule (and

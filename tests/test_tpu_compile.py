@@ -1,0 +1,120 @@
+"""The main path's Pallas kernels, compiled by Mosaic for a described v5e.
+
+No chip is needed: the TPU compiler compiles for a chip that is described
+and not attached (``jax.experimental.topologies``).  Each test compiles
+with ``interpret=False`` at the real width and asserts that the kernel is
+in the program (``tpu_custom_call``), so what interpret mode cannot see
+(tile alignment, VMEM budgets, a kernel that cannot be partitioned) fails
+here at no chip time.  Nothing runs: results and times come from
+``chip_smoke.py`` on the chip.
+
+The topology is described inside a module fixture, never at import: one
+process at a time may load libtpu, and under pytest-xdist every worker
+imports this file.  Keep these tests in this one file.
+
+The shallow-water kernel compiles at ``(tile_rows, fuse) = (64, 1)``
+(a few seconds); the default ``(120, 3)`` takes about 97 s here, too slow
+for tier-1, and is compiled on the chip by ``chip_smoke.py``.
+"""
+
+import os
+from functools import partial
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # compiles for a described chip are written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return Mesh(np.array(topo.devices[:4]), ("x",))
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sw_fused_step_compiles(one_chip):
+    from mpi4jax_tpu.models import _sw_pallas
+    from mpi4jax_tpu.models.shallow_water import SWParams, SWState
+
+    tile_rows, fuse = 64, 1
+    shape = (1802, 3602)  # 1800x3600 interior plus the 1-cell halo
+    nyp_pad = _sw_pallas._tiling(shape[0], tile_rows, fuse)[2]
+    padded = jax.ShapeDtypeStruct(
+        (nyp_pad, _sw_pallas._col_pad(shape[1])), jnp.float32,
+        sharding=one_chip)
+    step = jax.jit(partial(
+        _sw_pallas.fused_step, params=SWParams(dx=5e3, dy=5e3), first=False,
+        interpret=False, tile_rows=tile_rows, logical_shape=shape,
+        fuse=fuse))
+    _assert_kernel(step.lower(SWState(*(padded,) * 6)).compile())
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_flash_attention_compiles(topo, grad):
+    from mpi4jax_tpu.ops.flash import ring_flash_attention
+
+    mesh = Mesh(np.array(topo.devices[:1]), ("sp",))
+    fa = jax.shard_map(
+        partial(ring_flash_attention, axis="sp", causal=True,
+                interpret=False),
+        mesh=mesh, in_specs=(P(None, "sp"),) * 3, out_specs=P(None, "sp"),
+        check_vma=False)
+    spec = jax.ShapeDtypeStruct((4, 4096, 16, 128), jnp.bfloat16,
+                                sharding=NamedSharding(mesh, P()))
+    if grad:
+        fn = jax.grad(lambda q, k, v: jnp.sum(fa(q, k, v).astype(
+            jnp.float32)), argnums=(0, 1, 2))
+    else:
+        fn = fa
+    _assert_kernel(jax.jit(fn).lower(spec, spec, spec).compile())
+
+
+@pytest.mark.parametrize("kernel", ["ring_shift", "fused_ring_allreduce_sum"])
+def test_ici_kernel_compiles_on_four_chips(four_chips, kernel):
+    from mpi4jax_tpu.ops import pallas_collectives as pc
+
+    if kernel == "ring_shift":
+        def body(v):
+            return pc.ring_shift(v, "x", 1, interpret=False)
+        shape = (4 * 64, 128)
+    else:
+        def body(v):
+            return pc.fused_ring_allreduce_sum(
+                v.reshape(-1), "x", interpret=False)[None]
+        shape = (4, 4 * 4096 + 37)
+    fn = jax.jit(jax.shard_map(body, mesh=four_chips, in_specs=P("x"),
+                               out_specs=P("x"), check_vma=False))
+    spec = jax.ShapeDtypeStruct(shape, jnp.float32,
+                                sharding=NamedSharding(four_chips, P("x")))
+    _assert_kernel(fn.lower(spec).compile())

@@ -28,7 +28,7 @@ LAUNCHER = os.path.join(REPO, "mpi4jax_tpu", "runtime", "launch.py")
 
 
 def _port(slot):
-    return 45700 + (os.getpid() * 7 + slot * 13) % 900
+    return 25700 + (os.getpid() * 7 + slot * 13) % 900
 
 
 def _run(prog, np_, port, env_extra, *args, elastic=True, timeout=240,
@@ -326,28 +326,12 @@ def test_obs_parts_carry_generation(tmp_path):
 # ---- package level: the DP GPT-2 acceptance scenario ---------------
 
 
-def _jax_at_least_min():
-    try:
-        import jax
-
-        parts = []
-        for piece in jax.__version__.split(".")[:3]:
-            parts.append(int("".join(c for c in piece if c.isdigit()) or 0))
-        return tuple(parts) >= (0, 6, 0)
-    except Exception:
-        return False
-
-
-needs_package = pytest.mark.skipif(
-    not _jax_at_least_min(), reason="package gate: needs jax >= 0.6")
-
 #: documented loss-parity bound (docs/elasticity.md): the recovered
 #: run reshards the global batch over fewer ranks, so only float
 #: reassociation separates it from the uninterrupted trajectory
 LOSS_REL_BOUND = 1e-2
 
 
-@needs_package
 def test_gpt_dp_elastic_loss_parity(tmp_path):
     """np=3 DP GPT-2 training, rank 1 killed mid-job, shrink to np=2,
     resume from the last committed step: the final full-batch loss
@@ -375,7 +359,6 @@ def test_gpt_dp_elastic_loss_parity(tmp_path):
     assert rel <= LOSS_REL_BOUND, (loss_clean, loss_fault, rel)
 
 
-@needs_package
 def test_schedules_stay_valid_at_shrunk_sizes():
     """Dense renumbering keeps the verifier's contract: a rank-symmetric
     program's schedule verifies clean at np=3 AND at the shrunk np=2 —

@@ -20,7 +20,7 @@ REPO = os.path.dirname(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 PROGRAMS = os.path.join(REPO, "tests", "world_programs")
 
-_port = [45500]  # own range: test_world_tier.py counts up from 44100
+_port = [25500]  # own range: test_world_tier.py counts up from 24100
 
 
 def run_launcher(program, np_, timeout=180, env_extra=None, extra_args=()):
@@ -122,7 +122,7 @@ def test_abort_poisons_waiting_third_rank():
     # root-cause text) within seconds, NOT its own 60 s deadline.
     # Ranks are spawned directly (no launcher) so no reaper can race
     # the poison delivery; per-rank env carries different deadlines.
-    port = 46300 + os.getpid() % 500
+    port = 26300 + os.getpid() % 500
     base = dict(os.environ)
     base.pop("XLA_FLAGS", None)
     base.update({
@@ -189,7 +189,7 @@ def test_launcher_sigterm_forwards_and_reaps(tmp_path):
     env["MPI4JAX_TPU_LAUNCH_GRACE_S"] = "2"
     p = subprocess.Popen(
         [sys.executable, "-m", "mpi4jax_tpu.runtime.launch", "-n", "2",
-         "--port", str(46200 + os.getpid() % 500),
+         "--port", str(26200 + os.getpid() % 500),
          os.path.join(PROGRAMS, "hang_forever.py")],
         env=env, cwd=REPO,
     )
@@ -229,7 +229,7 @@ def test_launcher_sigint_escalates_past_ignoring_ranks(tmp_path):
     env["MPI4JAX_TPU_LAUNCH_GRACE_S"] = "1"
     p = subprocess.Popen(
         [sys.executable, "-m", "mpi4jax_tpu.runtime.launch", "-n", "2",
-         "--port", str(46250 + os.getpid() % 500),
+         "--port", str(26250 + os.getpid() % 500),
          os.path.join(PROGRAMS, "hang_forever.py")],
         env=env, cwd=REPO,
     )
@@ -267,7 +267,7 @@ def test_connect_deadline_reports_last_errno():
     env.update({
         "MPI4JAX_TPU_RANK": "1",
         "MPI4JAX_TPU_SIZE": "2",
-        "MPI4JAX_TPU_COORD": f"127.0.0.1:{46350 + os.getpid() % 500}",
+        "MPI4JAX_TPU_COORD": f"127.0.0.1:{26350 + os.getpid() % 500}",
         "MPI4JAX_TPU_CONNECT_TIMEOUT_S": "2",
         "JAX_PLATFORMS": "cpu",
     })

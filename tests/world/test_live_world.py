@@ -25,7 +25,7 @@ import tempfile
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-_port = [46900]
+_port = [26900]
 
 _PROG = r"""
 import hashlib, os, sys, types

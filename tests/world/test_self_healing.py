@@ -29,7 +29,7 @@ REPO = os.path.dirname(
 PROGRAMS = os.path.join(REPO, "tests", "world_programs")
 LAUNCHER = os.path.join(REPO, "mpi4jax_tpu", "runtime", "launch.py")
 
-_port = [48300]  # own range (uring_world counts in 47400+)
+_port = [28300]  # own range (uring_world counts in 27400+)
 
 # the armed layer plus fast, test-friendly backoff
 ARMED = {

@@ -36,9 +36,9 @@ PROGRAMS = os.path.join(REPO, "tests", "world_programs")
 # processes land in disjoint port windows instead of all racing for
 # one fixed base — the forced-ICI-leg launch was flaking on exactly
 # that collision.  Per-launch strides below keep launches within one
-# process apart; the 43200–44000 window is unused by the other world
+# process apart; the 23200–24000 window is unused by the other world
 # suites.
-_port = [43200 + (os.getpid() * 41) % 600]
+_port = [23200 + (os.getpid() * 41) % 600]
 
 
 def _launch(program, np_, fake_hosts, expect_islands, *, timeout=300,
@@ -137,10 +137,6 @@ def test_full_ops_hier_force_axis():
     # partition: every allreduce/allgather upgrades to a hierarchical
     # twin and every large bcast/reduce routes through the leaders —
     # numerics must hold end to end (the forced-ring axis's sibling).
-    # Package-level program: needs jax >= 0.6 like the other full-ops
-    # axes; skip cleanly elsewhere.
-    if not _jax_at_least_min():
-        pytest.skip("package gate: needs jax >= 0.6")
     _port[0] += 11
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
@@ -155,18 +151,6 @@ def test_full_ops_hier_force_axis():
     )
     assert res.returncode == 0, res.stderr + res.stdout
     assert res.stdout.count("full_ops OK") == 4
-
-
-def _jax_at_least_min():
-    try:
-        import jax
-
-        parts = []
-        for piece in jax.__version__.split(".")[:3]:
-            parts.append(int("".join(c for c in piece if c.isdigit()) or 0))
-        return tuple(parts) >= (0, 6, 0)
-    except Exception:
-        return False
 
 
 _ELASTIC_PROG = r"""

@@ -25,7 +25,7 @@ URING_OFF = {"MPI4JAX_TPU_URING": "0"}
 
 
 def _port(slot):
-    return 47400 + (os.getpid() * 7 + slot * 17) % 500
+    return 27400 + (os.getpid() * 7 + slot * 17) % 500
 
 
 _uring_status_cache = []
