@@ -236,6 +236,7 @@ def _col_pad(X: int) -> int:
     return -(-X // 128) * 128
 
 
+@jax.named_scope("sw_pad_rows")
 def pad_rows(state, *, tile_rows: int = 16, fuse: int = 1):
     """Zero-pad every field to the kernel's aligned block shape: rows up
     to a multiple of the row tile, columns up to a multiple of 128 (the
@@ -256,6 +257,7 @@ def pad_rows(state, *, tile_rows: int = 16, fuse: int = 1):
     )
 
 
+@jax.named_scope("sw_unpad_rows")
 def unpad_rows(state, logical_shape):
     nyp, X = logical_shape
     if state[0].shape == (nyp, X):
@@ -329,6 +331,8 @@ def fused_step(state, params, *, first: bool, interpret=None,
     fields = [f.astype(jnp.float32) for f in state]
     outs = pl.pallas_call(
         kern,
+        # the kernel's name in the HLO, in its op_name and in a trace
+        name="sw_fused_step",
         grid=(ntiles,),
         out_shape=(struct,) * 6,
         in_specs=[top_spec, body_spec, bot_spec] * 6,

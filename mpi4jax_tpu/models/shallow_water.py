@@ -36,6 +36,13 @@ from .. import ops
 from ..parallel.grid import ProcessGrid
 from ..parallel.halo import halo_exchange
 
+# The names the solver gives its parts inside a compiled program, which a
+# profiler trace shows in each instruction's op_name (docs/observability.md,
+# "Device scopes"): the halo exchange, the XLA step, the fused path's
+# padding and cut back, and its kernel.
+SCOPES = ("halo_exchange", "sw_step", "sw_pad_rows", "sw_unpad_rows",
+          "sw_fused_step")
+
 
 class SWParams(NamedTuple):
     dx: float
@@ -230,6 +237,9 @@ class ShallowWater:
         return SWState(h0, u0, v0, zero, zero, zero)
 
     # -- dynamics ---------------------------------------------------------
+    # every instruction of a step carries sw_step in its op_name; those of
+    # its exchanges carry halo_exchange inside it
+    @jax.named_scope("sw_step")
     def _step_local(self, state: SWState, first: bool) -> SWState:
         p = self.params
         dt = p.dt
@@ -459,8 +469,17 @@ class ShallowWater:
             out_specs=SWState(*(spec,) * 6),
             check_vma=False,
         )
+
+        # named programs: the HLO module and the trace's XLA Modules line
+        # tell a bootstrap call from an advance call
+        def sw_bootstrap(state):
+            return mapped(*state)
+
+        def sw_advance(state):
+            return mapped(*state)
+
         return jax.jit(
-            lambda state: mapped(*state),
+            sw_bootstrap if first else sw_advance,
             donate_argnums=(0,) if donate else (),
         )
 

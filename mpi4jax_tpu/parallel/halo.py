@@ -18,6 +18,7 @@ from __future__ import annotations
 
 from typing import Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -85,6 +86,10 @@ def _axis_exchange(f, dim: int, axis_name: str, halo: int, periodic: bool):
     return f
 
 
+# one scope over the stacking, the strips, the collectives, the ghost
+# writes and the unstacking: every instruction of an exchange carries it in
+# its op_name, so a profiler trace tells them from the caller's own
+@jax.named_scope("halo_exchange")
 def halo_exchange(
     f,
     grid: ProcessGrid,

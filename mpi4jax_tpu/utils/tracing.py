@@ -14,10 +14,9 @@ spliced into a JSON record corrupts it), and per-line atomicity keeps
 multi-rank output from interleaving mid-line (the native transport's
 mirror of this format, ``native/tpucomm.cc``, already behaves this way).
 
-The world tier logs at execution time from the host side.  The mesh tier
-executes on device inside a compiled program, so per-execution host
-logging is done via ``jax.debug.callback`` when tracing is enabled at
-trace time.
+The world tier logs at execution time from the host side.  Mesh-tier
+ops run on the device inside one compiled program and write no lines; a
+profiler trace shows them (docs/observability.md, "Device scopes").
 
 ``CallTrace`` additionally feeds the structured observability recorder
 (``mpi4jax_tpu.obs``) when it is armed: every traced op becomes a span
@@ -140,21 +139,3 @@ class CallTrace:
                     nbytes=self.nbytes, tag=self.tag, algo=self.algo,
                 )
         return False
-
-
-def trace_mesh_op(axis_rank, opname: str, details: str = "") -> None:
-    """Emit a device-side debug line for a mesh-tier op (if enabled).
-
-    Uses ``jax.debug.callback`` so the line is printed at *execution* time
-    with the concrete rank, matching the world-tier format.
-    """
-    if not logging_enabled():
-        return
-    import jax
-
-    call_id = new_call_id()
-
-    def _emit(r):
-        log_line(int(r), call_id, f"{opname} {details}".rstrip())
-
-    jax.debug.callback(_emit, axis_rank)

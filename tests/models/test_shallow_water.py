@@ -2,13 +2,15 @@
 correctness check — bitwise-comparable results between a 1-device and an
 8-device decomposition of the same problem."""
 
+import re
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import jax
 import mpi4jax_tpu as m4j
-from mpi4jax_tpu.models.shallow_water import ShallowWater, SWParams
+from mpi4jax_tpu.models.shallow_water import SCOPES, ShallowWater, SWParams
 from mpi4jax_tpu.parallel.grid import ProcessGrid
 
 NY, NX = 24, 48
@@ -57,3 +59,28 @@ def test_longer_run_stable():
     assert np.all(np.isfinite(h))
     # surface stays within physically plausible bounds around DEPTH=100
     assert 50 < h.mean() < 150
+
+
+@pytest.mark.parametrize("first,program", [(True, "sw_bootstrap"),
+                                           (False, "sw_advance")])
+def test_compiled_step_names_its_scopes(first, program):
+    """The step's instructions carry ``sw_step`` in their op_name, those
+    of its halo exchange ``sw_step/halo_exchange``, and the program is
+    named for its call: what a profiler trace reads them by."""
+    grid = ProcessGrid((2, 2), devices=jax.devices()[:4])
+    model = ShallowWater(grid, (NY, NX), PARAMS)
+    fn = model.step_fn(3, first=first)
+    text = fn.lower(model.init()).compile().as_text()
+    assert text.startswith(f"HloModule jit_{program}")
+    names = set(re.findall(r'op_name="([^"]+)"', text))
+    assert all(n.startswith(f"jit({program})/") for n in names
+               if "/" in n)
+    steps = [n for n in names if "/sw_step/" in n]
+    halo = [n for n in steps if "/sw_step/halo_exchange/" in n]
+    assert halo and len(halo) < len(steps)
+    assert any(n.endswith("/halo_exchange/ppermute") for n in halo)
+    assert any(n.endswith("/halo_exchange/dynamic_update_slice")
+               for n in halo)
+    # of the solver's declared names, the XLA step carries these two
+    assert {p for n in names for p in n.split("/") if p in SCOPES} == {
+        "sw_step", "halo_exchange"}

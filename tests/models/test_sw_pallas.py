@@ -5,12 +5,14 @@ reassociation tolerance, bootstrap (Euler) step included.  Runs the
 kernel under the Pallas TPU interpreter on the CPU mesh.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mpi4jax_tpu.models.shallow_water import ShallowWater, SWParams
+from mpi4jax_tpu.models.shallow_water import SCOPES, ShallowWater, SWParams
 from mpi4jax_tpu.parallel.grid import ProcessGrid
 
 
@@ -103,3 +105,20 @@ def test_auto_impl_surfaces_kernel_compile_error(monkeypatch):
     state = model.init()
     with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
         model.step_fn(4, first=True, impl="auto")(state)
+
+
+def test_fused_path_names_its_scopes_and_kernel():
+    """The interpreted fused path: the padding to the kernel's block and
+    the cut back carry ``sw_pad_rows`` and ``sw_unpad_rows`` in their
+    op_name, and the kernel's instructions its name, ``sw_fused_step``."""
+    model = _model()  # 34 x 66 padded to the kernel's 128-lane block
+    text = model.step_fn(4, impl="pallas").lower(
+        model.init()).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]+)"', text))
+    assert any("/sw_pad_rows/" in n for n in names)
+    assert any("/sw_unpad_rows/" in n for n in names)
+    assert any("/sw_fused_step/" in n for n in names)
+    assert not any("/sw_step/" in n for n in names)
+    # of the solver's declared names, the fused path carries these three
+    assert {p for n in names for p in n.split("/") if p in SCOPES} == {
+        "sw_pad_rows", "sw_unpad_rows", "sw_fused_step"}
