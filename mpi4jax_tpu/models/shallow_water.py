@@ -381,9 +381,10 @@ class ShallowWater:
         ``tile_rows``/``fuse`` tune the Pallas path: row-tile height and
         temporal blocking factor (``fuse`` steps per HBM round-trip —
         see ``_sw_pallas.fused_step``).  Defaults tuned on a v5e at the
-        flagship (1800, 3600) size: 120/3 ≈ 0.69 ms/step; larger
-        windows (144/3, 128/4, 120/5) overflow what the Mosaic compiler
-        will build.
+        flagship (1800, 3600) size: 120/3 ≈ 0.519 ms/step (one TPU v5e,
+        ``perf/run.py --workload sw_ref.periodic``); larger windows
+        (144/3, 128/4, 120/5) overflow what the Mosaic compiler will
+        build.
         """
         gy, gx = self.grid.shape
         if impl not in ("auto", "xla", "pallas"):
@@ -443,11 +444,22 @@ class ShallowWater:
                 remaining = n_steps
             if fused_steps is not None and fz > 1:
                 # temporal blocking: whole fused calls, then the
-                # remainder one step at a time
+                # remainder one step at a time.  The calls go two per
+                # loop iteration (ping-pong): the kernel writes fresh
+                # buffers while it still reads the carry, so with one
+                # call per body XLA copies all six outputs back into the
+                # carry every iteration; with two, the carry is dead once
+                # the first call has read it and the second call's
+                # outputs take its buffers.  (Aliasing a call's outputs
+                # onto its own inputs is unsound: tile i+1 reads tile
+                # i's rows as its top halo.)
                 whole, rest = divmod(remaining, fz)
-                if whole > 0:
+                if whole > 1:
                     s = lax.fori_loop(
-                        0, whole, lambda _, st: fused_steps(st), s)
+                        0, whole // 2,
+                        lambda _, st: fused_steps(fused_steps(st)), s)
+                if whole % 2:
+                    s = fused_steps(s)
                 for _ in range(rest):
                     s = one_step(s, False)
             elif remaining > 0:

@@ -72,6 +72,46 @@ def test_fused_step_multi_tile(tile_rows, fuse):
         )
 
 
+@pytest.mark.parametrize("first,n_steps", [
+    (False, 2), (False, 3), (False, 7), (False, 10), (False, 25),
+    (True, 25)])
+def test_fused_loop_matches_kernel_calls(first, n_steps):
+    """The time loop runs its fused calls two per iteration: an even, an
+    odd and a zero count of whole fused calls, with and without single
+    steps after them, give bit for bit what the same kernel calls give
+    made one by one."""
+    from mpi4jax_tpu.models import _sw_pallas
+
+    model = _model()
+    state = model.init()
+    got = model.step_fn(n_steps, first=first, impl="pallas")(state)
+
+    tile_rows, fuse = 120, 3  # step_fn's defaults
+    shape = state.h.shape
+    T_eff = _sw_pallas._tiling(shape[0], tile_rows, fuse)[0]
+    s = _sw_pallas.pad_rows(state, tile_rows=tile_rows, fuse=fuse)
+
+    def call(st, is_first, tr, fz):
+        return _sw_pallas.fused_step(
+            st, model.params, first=is_first, logical_shape=shape,
+            tile_rows=tr, fuse=fz)
+
+    remaining = n_steps
+    if first:
+        s = call(s, True, T_eff, 1)
+        remaining -= 1
+    whole, rest = divmod(remaining, fuse)
+    for _ in range(whole):
+        s = call(s, False, tile_rows, fuse)
+    for _ in range(rest):
+        s = call(s, False, T_eff, 1)
+    want = _sw_pallas.unpad_rows(s, shape)
+    for name, a, b in zip(want._fields, got, want):
+        np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b),
+            err_msg=f"field {name} after {n_steps} steps, first={first}")
+
+
 def test_fused_step_conserves_mass():
     model = _model()
     s0 = model.init()

@@ -14,10 +14,14 @@ imports this file.  Keep these tests in this one file.
 
 The shallow-water kernel compiles at ``(tile_rows, fuse) = (64, 1)``
 (a few seconds); the default ``(120, 3)`` takes about 97 s here, too slow
-for tier-1, and is compiled on the chip by ``chip_smoke.py``.
+for tier-1, and is compiled on the chip by ``chip_smoke.py``.  Its time loop
+(two fused calls per iteration, so that XLA copies no loop carry) is
+checked here at a small domain, and at the flagship domain by the one
+test that compiles for an attached chip and skips elsewhere.
 """
 
 import os
+import re
 from functools import partial
 
 import numpy as np
@@ -78,6 +82,65 @@ def test_sw_fused_step_compiles(one_chip):
         interpret=False, tile_rows=tile_rows, logical_shape=shape,
         fuse=fuse))
     _assert_kernel(step.lower(SWState(*(padded,) * 6)).compile())
+
+
+def _while_bodies(hlo):
+    """``{name: instruction lines}`` of each while loop body in HLO text."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if m:
+            name, comps[m.group(1)] = m.group(1), []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            comps[name].append(line)
+    return {b: comps[b] for b in re.findall(r"\bbody=%?([\w.\-]+)", hlo)}
+
+
+def _assert_loop_carries_no_copies(devices, ny, nx):
+    """Compile a 25-step call of the fused time loop (8 whole fused calls,
+    the default ``tile_rows=120, fuse=3``) for ``devices[0]``: no while
+    body copies a padded field (the loop carry of the kernel's outputs),
+    and each body holds two ``sw_fused_step`` calls."""
+    from mpi4jax_tpu.models import _sw_pallas
+    from mpi4jax_tpu.models.shallow_water import (ShallowWater, SWParams,
+                                                  SWState)
+    from mpi4jax_tpu.parallel.grid import ProcessGrid
+
+    grid = ProcessGrid((1, 1), devices=devices[:1])
+    model = ShallowWater(grid, (ny, nx), SWParams(dx=5e3, dy=5e3))
+    sharding = NamedSharding(grid.mesh, P(*grid.axes))
+    state = SWState(*(jax.ShapeDtypeStruct(
+        model.stacked_shape, jnp.float32, sharding=sharding),) * 6)
+    with jax.set_mesh(grid.mesh):
+        hlo = model.step_fn(25, impl="pallas").lower(state).compile(
+            ).as_text()
+    padded = "f32[{},{}]".format(_sw_pallas._tiling(ny + 2, 120, 3)[2],
+                                 _sw_pallas._col_pad(nx + 2))
+    bodies = _while_bodies(hlo)
+    assert bodies
+    for name, lines in bodies.items():
+        copies = [ln for ln in lines if re.search(
+            r"= " + re.escape(padded) + r"\S* copy(-start)?\(", ln)]
+        kernels = [ln for ln in lines
+                   if " custom-call(" in ln and "sw_fused_step" in ln]
+        assert not copies, f"{name}: {copies}"
+        assert len(kernels) == 2, f"{name}: {len(kernels)} kernel calls"
+
+
+def test_sw_fused_loop_carries_no_copies(topo):
+    """A small domain, so that the default tiling compiles in seconds."""
+    _assert_loop_carries_no_copies(topo.devices, 240, 512)
+
+
+def test_sw_fused_loop_carries_no_copies_on_chip():
+    """The flagship domain, whose fields live in HBM, on the attached chip:
+    ``python -m pytest --noconftest tests/test_tpu_compile.py -k on_chip``
+    on a TPU host (the suite's conftest holds JAX to the CPU)."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("compiles for the attached TPU")
+    _assert_loop_carries_no_copies(jax.devices(), 1800, 3600)
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
