@@ -21,6 +21,13 @@ execution of every call), then the measured window of ``--seconds``
 (``--trace 0``) or ``trace_solves`` whole solves under the profiler
 (``--trace 1``), then the check of finished solves against the
 reference, which runs after the program's state is freed.
+
+A metric reader gets the run's context: ``setup_s`` and its split
+``setup`` (``init_s``, ``generate_s``, ``warm_up_s``, ``compile_s``), the
+driven ``window``, the reduced ``trace`` (``--trace 1`` only; each
+instruction in one of the system's ``SCOPES`` where it names them), the
+system's ``counters`` after the window (``Cell.counters()`` where the
+system has it, else empty) and the chips' ``device_kind``.
 """
 
 from __future__ import annotations
@@ -40,6 +47,10 @@ PERF = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(PERF)
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
                   "/jax/core/compile/jaxpr_trace_duration")
+# the spans of tracing, lowering and compiling (or loading from the
+# cache) a program; those of an inner program nest in its caller's
+COMPILE_SPANS = COMPILE_EVENTS + (
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",)
 
 
 class NoDevice(Exception):
@@ -169,14 +180,27 @@ def drive(cell, *, seconds=None, solves=None):
 
 
 class _CompileCounter(contextlib.AbstractContextManager):
-    """Counts traces and compiles while it is entered."""
+    """Counts traces and compiles while it is entered, and keeps the
+    host-clock ``(start, end)`` of each trace, lowering and compile."""
 
     def __init__(self):
         self.count = 0
+        self.spans = []
 
     def _listen(self, event, duration, **kwargs):
         if event in COMPILE_EVENTS:
             self.count += 1
+        if event in COMPILE_SPANS:
+            # JAX reports each span as it ends
+            end = time.perf_counter()
+            self.spans.append((end - duration, end))
+
+    def seconds(self):
+        """Host seconds in which some program was traced, lowered or
+        compiled: the union of the spans, which nest."""
+        from perf.tracefile import length, union
+
+        return length(union(self.spans))
 
     def __enter__(self):
         import jax
@@ -251,10 +275,17 @@ def run(spec, seed, seconds, trace, *, t_start, devices):
 
     system = importlib.import_module(
         f"perf.systems.{spec['config']['system']}")
-    cell = system.Cell(spec["config"], spec["traffic"], devices, seed)
-    cell.generate()
-    cell.warm_up()
-    setup_s = time.perf_counter() - t_start
+    with _CompileCounter() as compiles:
+        cell = system.Cell(spec["config"], spec["traffic"], devices, seed)
+        t_init = time.perf_counter()
+        cell.generate()
+        t_generated = time.perf_counter()
+        cell.warm_up()
+        t_warm = time.perf_counter()
+    setup_s = t_warm - t_start
+    setup = {"init_s": t_init - t_start, "generate_s": t_generated - t_init,
+             "warm_up_s": t_warm - t_generated,
+             "compile_s": compiles.seconds()}
 
     reduced = None
     if trace:
@@ -268,15 +299,17 @@ def run(spec, seed, seconds, trace, *, t_start, devices):
         finally:
             jax.profiler.stop_trace()
         reduced = tracefile.reduce(tracefile.find_xplane(trace_dir),
-                                   cell.devices)
+                                   cell.devices,
+                                   getattr(system, "SCOPES", ()))
         shutil.rmtree(trace_dir, ignore_errors=True)
     else:
         window = drive(cell, seconds=seconds)
     peak = memory_peak(cell.devices)
+    counters = cell.counters() if hasattr(cell, "counters") else {}
 
-    # what a metric reader may read: set-up time, the driven window
-    # (calls, steps, seconds), the reduced trace (--trace 1 only)
-    ctx = SimpleNamespace(setup_s=setup_s, window=window, trace=reduced)
+    ctx = SimpleNamespace(setup_s=setup_s, setup=setup, window=window,
+                          trace=reduced, counters=counters,
+                          device_kind=cell.devices[0].device_kind)
     listed = spec["per_layer"] if trace else spec["end_to_end"]
     kind = "layer_metrics" if trace else "end_to_end"
     metrics = {}
@@ -301,5 +334,6 @@ def run(spec, seed, seconds, trace, *, t_start, devices):
         result["breakdown"] = reduced.breakdown()
     result["compiles_in_window"] = window.compiles
     result["solve_seconds"] = window.solve_seconds
+    result["setup"] = setup
     result["check"] = readings
     return result

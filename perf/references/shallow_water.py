@@ -4,21 +4,27 @@ The scheme of the mpi4jax shallow-water benchmark
 (https://mpi4jax.readthedocs.io/en/latest/shallow-water.html): Sadourny's
 energy-conserving C-grid discretisation of the nonlinear shallow-water
 equations, Adams-Bashforth time stepping (a = 1.6, b = -0.6, the first
-step forward Euler), lateral viscosity 1e-3 * f * dx**2, periodic in x,
-walls in y.
+step forward Euler), lateral viscosity 1e-3 * f * dx**2, walls in y, and
+in x either periodic or walls (the configuration's ``periodic_x``).
 
 Everything here is one global domain in straightforward ``jax.numpy``:
 no decomposition, no halo exchange, no kernel, and nothing imported from
 the package under test.  Fields are ``(ny + 2, nx + 2)`` with one ghost
 ring, the layout a user of the solver reads back:
 
-- ghost columns repeat the opposite interior column (periodic x);
+- periodic x: ghost columns repeat the opposite interior column;
+- walls in x: ghost columns are wall values that no step changes, like
+  the ghost rows (``h`` continues its edge column, ``u`` keeps the jet,
+  ``v`` is zero), and ``u`` on the last interior column is the
+  no-normal-flow east wall (zero);
 - ghost rows are wall values that no step changes: ``h`` continues its
   edge row, ``u`` keeps the jet profile evaluated one row beyond the
   edge, ``v`` is zero;
 - ``v`` on the last interior row is the no-normal-flow wall (zero);
 - flux, vorticity, energy and viscous-flux fields are zero on their
-  ghost rows.
+  ghost rows, and between walls in x on their ghost columns;
+- the layer thickness used for fluxes is edge-valued on its wall rows,
+  and between walls in x on its wall columns.
 
 ``dtype`` selects the precision of every field and every operation:
 float32 is the reference, a lower one is the control that the
@@ -36,7 +42,8 @@ import numpy as np
 DAY_SECONDS = 86_400.0
 AB_A, AB_B = 1.6, -0.6
 # the constants a step reads from the configuration
-CONSTANTS = ("dx", "dy", "gravity", "depth", "coriolis_f", "coriolis_beta")
+CONSTANTS = ("dx", "dy", "gravity", "depth", "coriolis_f", "coriolis_beta",
+             "periodic_x")
 
 
 def time_step(config):
@@ -57,7 +64,11 @@ def _at(a, dj, di):
     return a[1 + dj:1 + dj + ny, 1 + di:1 + di + nx]
 
 
-def _periodic_x(a):
+def _x_ghosts(a, periodic):
+    """Periodic x: each ghost column repeats the opposite interior
+    column.  Walls in x: the ghost columns keep what they hold."""
+    if not periodic:
+        return a
     return a.at[:, 0].set(a[:, -2]).at[:, -1].set(a[:, 1])
 
 
@@ -65,13 +76,19 @@ def _v_wall(a):
     return a.at[-2, :].set(0)
 
 
-def _ring0(x):
-    """Interior values with a zero ghost ring, then periodic in x."""
-    return _periodic_x(jnp.pad(x, 1))
+def _u_wall(a, periodic):
+    """Walls in x: no flow through the east wall, a u-point field's last
+    interior column (ghost rows included) is zero."""
+    return a if periodic else a.at[:, -2].set(0)
 
 
-def _set_interior(a, x):
-    return _periodic_x(a.at[1:-1, 1:-1].set(x))
+def _ring0(x, periodic):
+    """Interior values with a zero ghost ring, then the x ghosts."""
+    return _x_ghosts(jnp.pad(x, 1), periodic)
+
+
+def _set_interior(a, x, periodic):
+    return _x_ghosts(a.at[1:-1, 1:-1].set(x), periodic)
 
 
 # -- initial state ---------------------------------------------------------
@@ -81,10 +98,10 @@ def initial_state(config, modes, dtype=jnp.float32):
     ``modes`` is an ``(m, 5)`` array of ``(amplitude, kx, ky, phase_x,
     phase_y)``: each adds ``amplitude * sin(2 pi kx x / Lx + phase_x) *
     cos(pi ky y / Ly + phase_y)`` to ``h``.  Integer ``kx`` keeps ``h``
-    periodic in x.  Returns ``(h, u, v, dh, du, dv)``.
+    periodic in x; between walls any ``kx`` serves.  Returns ``(h, u, v,
+    dh, du, dv)``.
     """
-    if not config["periodic_x"]:
-        raise ValueError("the reference implements periodic x only")
+    periodic = config["periodic_x"]
     ny, nx = config["ny"], config["nx"]
     dx, dy = config["dx"], config["dy"]
     f0, beta, g = (config["coriolis_f"], config["coriolis_beta"],
@@ -107,8 +124,8 @@ def initial_state(config, modes, dtype=jnp.float32):
         h_int = h_int + amp * (
             jnp.sin(2 * jnp.pi * kx * xi / lx + px)
             * jnp.cos(jnp.pi * ky * yi / ly + py))
-    h = _periodic_x(jnp.pad(h_int, 1, mode="edge"))
-    u = _periodic_x(u)
+    h = _x_ghosts(jnp.pad(h_int, 1, mode="edge"), periodic)
+    u = _u_wall(_x_ghosts(u, periodic), periodic)
     v = jnp.zeros_like(u)
     zero = jnp.zeros_like(u)
     return tuple(a.astype(dtype) for a in (h, u, v, zero, zero, zero))
@@ -124,23 +141,28 @@ def step(state, config, first):
     dt = time_step(config)
     nu = 1e-3 * f0 * dx ** 2
     ny = h.shape[0] - 2
+    per = config["periodic_x"]
 
-    # layer thickness with edge-valued wall rows
+    # layer thickness with edge-valued wall rows (and wall columns)
     hh = h.at[0].set(h[1]).at[-1].set(h[-2])
+    if not per:
+        hh = hh.at[:, 0].set(hh[:, 1]).at[:, -1].set(hh[:, -2])
 
     # mass fluxes, potential vorticity and kinetic energy
-    fe = _ring0(0.5 * (_at(hh, 0, 0) + _at(hh, 0, 1)) * _at(u, 0, 0))
+    fe = _u_wall(_ring0(0.5 * (_at(hh, 0, 0) + _at(hh, 0, 1))
+                        * _at(u, 0, 0), per), per)
     fn = _v_wall(_ring0(0.5 * (_at(hh, 0, 0) + _at(hh, 1, 0))
-                        * _at(v, 0, 0)))
+                        * _at(v, 0, 0), per))
     zeta = ((_at(v, 0, 1) - _at(v, 0, 0)) / dx
             - (_at(u, 1, 0) - _at(u, 0, 0)) / dy)
     thickness = 0.25 * (_at(hh, 0, 0) + _at(hh, 0, 1)
                         + _at(hh, 1, 0) + _at(hh, 1, 1))
     y = (jnp.arange(ny, dtype=dtype) * dy)[:, None]
     coriolis = f0 + beta * y
-    q = _ring0((coriolis + zeta) / thickness)
+    q = _ring0((coriolis + zeta) / thickness, per)
     ke = _ring0(0.5 * (0.5 * (_at(u, 0, 0) ** 2 + _at(u, 0, -1) ** 2)
-                       + 0.5 * (_at(v, 0, 0) ** 2 + _at(v, -1, 0) ** 2)))
+                       + 0.5 * (_at(v, 0, 0) ** 2 + _at(v, -1, 0) ** 2)),
+                per)
 
     # tendencies
     dh_new = (-(_at(fe, 0, 0) - _at(fe, 0, -1)) / dx
@@ -158,20 +180,25 @@ def step(state, config, first):
 
     # Adams-Bashforth (forward Euler on the first step)
     a, b = (1.0, 0.0) if first else (AB_A, AB_B)
-    h = _set_interior(h, _at(h, 0, 0) + dt * (a * dh_new + b * _at(dh, 0, 0)))
-    u = _set_interior(u, _at(u, 0, 0) + dt * (a * du_new + b * _at(du, 0, 0)))
+    h = _set_interior(
+        h, _at(h, 0, 0) + dt * (a * dh_new + b * _at(dh, 0, 0)), per)
+    u = _u_wall(_set_interior(
+        u, _at(u, 0, 0) + dt * (a * du_new + b * _at(du, 0, 0)), per), per)
     v = _v_wall(_set_interior(
-        v, _at(v, 0, 0) + dt * (a * dv_new + b * _at(dv, 0, 0))))
+        v, _at(v, 0, 0) + dt * (a * dv_new + b * _at(dv, 0, 0)), per))
 
     # lateral viscosity: divergence of nu * grad, zero flux through walls
     def viscous(c):
-        flux_x = _ring0(nu * (_at(c, 0, 1) - _at(c, 0, 0)) / dx)
-        flux_y = _v_wall(_ring0(nu * (_at(c, 1, 0) - _at(c, 0, 0)) / dy))
+        flux_x = _u_wall(_ring0(nu * (_at(c, 0, 1) - _at(c, 0, 0)) / dx,
+                                per), per)
+        flux_y = _v_wall(_ring0(nu * (_at(c, 1, 0) - _at(c, 0, 0)) / dy,
+                                per))
         return ((_at(flux_x, 0, 0) - _at(flux_x, 0, -1)) / dx
                 + (_at(flux_y, 0, 0) - _at(flux_y, -1, 0)) / dy)
 
-    u, v = (_set_interior(u, _at(u, 0, 0) + dt * viscous(u)),
-            _v_wall(_set_interior(v, _at(v, 0, 0) + dt * viscous(v))))
+    u, v = (_u_wall(_set_interior(u, _at(u, 0, 0) + dt * viscous(u), per),
+                    per),
+            _v_wall(_set_interior(v, _at(v, 0, 0) + dt * viscous(v), per)))
     return (h, u, v, jnp.pad(dh_new, 1), jnp.pad(du_new, 1),
             jnp.pad(dv_new, 1))
 

@@ -16,10 +16,14 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from mpi4jax_tpu.models import shallow_water
 from perf import schedule
 from perf.references import shallow_water as reference
 
 FIELDS = ("h", "u", "v")
+# the program's named scopes and kernel: a traced run's reduction gives
+# each instruction the innermost of them in its op_name
+SCOPES = shallow_water.SCOPES
 
 
 class Cell:

@@ -137,7 +137,7 @@ def test_scopes_partition_what_the_reduction_reads(traces, which):
             assert by_class.get(cls, 0.0) == pytest.approx(
                 chip.seconds(cls), rel=1e-9, abs=1e-15)
         assert sum(split[dev].values()) == pytest.approx(
-            sum(e - s for s, e, _ in chip.ops), rel=1e-9)
+            sum(e - s for s, e, _, _ in chip.ops), rel=1e-9)
 
 
 @pytest.mark.parametrize("which", ["1x1", "2x2"])
@@ -232,3 +232,108 @@ def test_command_prints_the_summary(traces, capsys):
     assert line["device_ops"][0][0] == (
         "sw_fused_step custom-call:tpu_custom_call")
 
+
+
+# -- the scopes in the benchmark's own reduction ---------------------------
+READERS = ("device.idle_share", "kernel.sw_fused_ms_per_step",
+           "step.xla_ms_per_step", "halo.collective_ms_per_step",
+           "halo.exposed_ms_per_step")
+SCOPE_READERS = ("halo.pack_ms_per_step", "step.stencil_ms_per_step",
+                 "step.pad_ms_per_step", "step.unscoped_ms_per_step")
+
+
+def _read(names, trace):
+    from types import SimpleNamespace
+
+    from perf import harness
+
+    window = harness.Window()
+    window.steps = STEPS
+    run = SimpleNamespace(trace=trace, window=window)
+    return {n: harness.load_module("layer_metrics", n).read(run)
+            for n in names}
+
+
+def _reduced(traces, which, names):
+    path, _, n = traces[which]
+    return tracefile.reduce(path, [_Device(i) for i in range(n)], names)
+
+
+@pytest.mark.parametrize("which", ["1x1s", "2x2s"])
+def test_readers_read_the_same_with_scopes(traces, which):
+    plain = _reduced(traces, which, ())
+    scoped = _reduced(traces, which, SCOPES)
+    assert not plain.scoped and scoped.scoped
+    want, got = _read(READERS, plain), _read(READERS, scoped)
+    assert set(want) == set(got)
+    for name, value in want.items():
+        if value is None:
+            assert got[name] is None, name
+        else:
+            assert got[name] == pytest.approx(value, rel=1e-12), name
+    assert scoped.busy_s() == pytest.approx(plain.busy_s(), rel=1e-12)
+    assert scoped.breakdown()["idle_gaps"] == plain.breakdown()["idle_gaps"]
+
+
+@pytest.mark.parametrize("which", ["1x1s", "2x2s"])
+def test_scoped_xla_seconds_add_up_to_the_xla_seconds(traces, which):
+    trace = _reduced(traces, which, SCOPES)
+    for chip in trace.chips.values():
+        found = {sc for _, _, _, sc in chip.ops}
+        assert found <= set(SCOPES) | {scopes.UNSCOPED}
+        assert sum(chip.seconds("xla", sc) for sc in found) == pytest.approx(
+            chip.seconds("xla"), rel=1e-9)
+        assert chip.seconds(scope=scopes.UNSCOPED) == pytest.approx(
+            sum(chip.seconds(cls, scopes.UNSCOPED)
+                for cls in ("kernel", "collective", "xla")), rel=1e-12)
+
+
+def test_without_scopes_every_instruction_is_unscoped(traces):
+    trace = _reduced(traces, "2x2s", ())
+    for chip in trace.chips.values():
+        assert {sc for _, _, _, sc in chip.ops} == {scopes.UNSCOPED}
+    # and no reader claims a split it could not make
+    assert all(v is None for v in _read(SCOPE_READERS, trace).values())
+
+
+@pytest.mark.parametrize("which", ["1x1s", "2x2s"])
+def test_scope_readers_give_the_offline_split(traces, which):
+    """The readers of ``perf/layer_metrics`` against ``perf/scopes.py``'s
+    split of the same recording, and its pinned numbers."""
+    got = _read(SCOPE_READERS, _reduced(traces, which, SCOPES))
+    ms = _ms(traces, which)
+    want = {
+        "halo.pack_ms_per_step": ms.get("halo_exchange xla"),
+        "step.stencil_ms_per_step": ms.get("sw_step xla"),
+        "step.pad_ms_per_step": (
+            ms["sw_pad_rows xla"] + ms["sw_unpad_rows xla"]
+            if "sw_pad_rows xla" in ms else None),
+        "step.unscoped_ms_per_step": sum(
+            v for k, v in ms.items() if k.startswith("unscoped ")),
+    }
+    for name, value in want.items():
+        if value is None:
+            assert got[name] is None, name
+        else:
+            assert got[name] == pytest.approx(value, rel=1e-9), name
+    pins = PINNED[which]
+    if which == "1x1s":
+        assert got["step.pad_ms_per_step"] == pytest.approx(
+            pins["pad"] + pins["unpad"], rel=1e-9)
+    else:
+        assert got["halo.pack_ms_per_step"] == pytest.approx(
+            pins["halo"], rel=1e-9)
+        assert got["step.stencil_ms_per_step"] == pytest.approx(
+            pins["step"], rel=1e-9)
+    assert got["step.unscoped_ms_per_step"] == pytest.approx(
+        pins["unscoped"], rel=1e-9)
+
+
+def test_breakdown_names_each_instruction_by_scope(traces):
+    trace = _reduced(traces, "1x1s", SCOPES)
+    ops = dict(trace.breakdown(top=100)["device_ops"])
+    assert ops["sw_fused_step custom-call:tpu_custom_call"] == pytest.approx(
+        dict(_reduced(traces, "1x1s", ()).breakdown()["device_ops"])[
+            "custom-call:tpu_custom_call"], rel=1e-12)
+    assert all(name.split(" ")[0] in set(SCOPES) | {scopes.UNSCOPED}
+               for name in ops)

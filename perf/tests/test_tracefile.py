@@ -106,7 +106,7 @@ def test_opcodes_and_counts():
 def test_one_chip_kernel_trace(traces):
     trace = _reduce(traces, "1x1")
     (chip,) = trace.chips.values()
-    ops = [op for _, _, op in chip.ops]
+    ops = [op for _, _, op, _ in chip.ops]
     assert ops.count("custom-call:tpu_custom_call") == 18
     assert not chip.collective()
     m = _metrics(trace)
@@ -124,7 +124,7 @@ def test_four_chip_halo_trace(traces):
     trace = _reduce(traces, "2x2")
     assert sorted(trace.chips) == [0, 1, 2, 3]
     for chip in trace.chips.values():
-        ops = [op for _, _, op in chip.ops]
+        ops = [op for _, _, op, _ in chip.ops]
         assert ops.count("collective-permute-start") == 1500
         assert ops.count("collective-permute-done") == 1500
         exposed = tracefile.length(chip.exposed_collective())

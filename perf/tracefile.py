@@ -9,6 +9,12 @@ its ``-start`` to its ``-done``.  The host plane ``/host:CPU`` holds the
 harness's own spans (``perf.window``, ``perf.prepare``,
 ``perf.dispatch``, ``perf.sync``) on the same clock.
 
+Given the program's scope names, each instruction also carries its
+scope: the innermost of those names in its JAX op_name, or ``unscoped``
+(``perf/scopes.py``, which reads the op_names from the same bytes).
+Without them every instruction is ``unscoped`` and the op_names are not
+read.
+
 An instruction's class comes from its HLO opcode, read from that text:
 
 - ``kernel``: a Mosaic kernel (``custom-call`` to ``tpu_custom_call``,
@@ -111,23 +117,27 @@ class Chip:
     """One chip's instructions inside the window, times in seconds."""
 
     def __init__(self, ops, in_flight):
-        self.ops = ops              # [(start, end, opcode)], leaf ops
-        self.in_flight = in_flight  # [(start, end, opcode)], async spans
+        self.ops = ops              # [(start, end, opcode, scope)], leaf ops
+        self.in_flight = in_flight  # [(start, end, opcode, scope)], async
 
-    def seconds(self, cls):
-        return sum(e - s for s, e, op in self.ops if op_class(op) == cls)
+    def seconds(self, cls=None, scope=None):
+        """Instruction seconds of class ``cls`` in ``scope``; None is
+        any."""
+        return sum(e - s for s, e, op, sc in self.ops
+                   if (cls is None or op_class(op) == cls)
+                   and (scope is None or sc == scope))
 
     def busy(self):
-        return union((s, e) for s, e, _ in self.ops)
+        return union((s, e) for s, e, _, _ in self.ops)
 
     def collective(self):
         """When a collective runs or is in flight on this chip."""
-        return union([(s, e) for s, e, op in self.ops + self.in_flight
+        return union([(s, e) for s, e, op, _ in self.ops + self.in_flight
                       if op_class(op) == "collective"])
 
     def exposed_collective(self):
         """Collective time during which no other instruction runs."""
-        others = union((s, e) for s, e, op in self.ops
+        others = union((s, e) for s, e, op, _ in self.ops
                        if op_class(op) != "collective")
         return subtract(self.collective(), others)
 
@@ -135,10 +145,11 @@ class Chip:
 class Trace:
     """The reduced trace of one traced window."""
 
-    def __init__(self, chips, window, host_spans):
+    def __init__(self, chips, window, host_spans, scoped=False):
         self.chips = chips            # {device id: Chip}
         self.window = window          # (start, end) of perf.window
         self.host_spans = host_spans  # [(start, end, name)]
+        self.scoped = scoped          # reduced with the program's scopes
 
     def window_s(self):
         return self.window[1] - self.window[0]
@@ -167,10 +178,14 @@ class Trace:
         return best[2] if best else "none"
 
     def breakdown(self, top=10):
+        """The instructions that took most time, averaged over chips and
+        named ``<scope> <opcode>`` where the trace is scoped, and the
+        longest idle gaps by the harness span open in each."""
         per_op = Counter()
         for chip in self.chips.values():
-            for s, e, op in chip.ops:
-                per_op[op] += (e - s) / len(self.chips)
+            for s, e, op, sc in chip.ops:
+                per_op[f"{sc} {op}" if self.scoped else op] += (
+                    (e - s) / len(self.chips))
         gaps = sorted(self.idle_gaps(), reverse=True)[:top]
         return {
             "device_ops": [[op, t] for op, t in per_op.most_common(top)],
@@ -184,22 +199,33 @@ def _events(line):
         yield ev.start_ns * 1e-9, ev.end_ns * 1e-9, ev.name
 
 
-def reduce(path, devices):
-    """The :class:`Trace` of ``devices`` (jax devices) in ``path``."""
+def _ops(line, scope_of, unscoped):
+    return [(s, e, opcode(n), scope_of.get(n, unscoped))
+            for s, e, n in _events(line)]
+
+
+def reduce(path, devices, scopes=()):
+    """The :class:`Trace` of ``devices`` (jax devices) in ``path`` (an
+    ``.xplane.pb``, gzipped or not), each instruction in the innermost of
+    ``scopes`` that its op_name names."""
     from jax.profiler import ProfileData
 
-    data = ProfileData.from_file(path)
+    from perf.scopes import UNSCOPED, event_scopes, load, op_names
+
+    buf = load(path)
+    data = ProfileData.from_serialized_xspace(buf)
     wanted = {f"/device:TPU:{d.id}": d.id for d in devices}
+    names = op_names(buf, set(wanted)) if scopes else {}
     raw = {}
     host = []
     for plane in data.planes:
         if plane.name in wanted:
+            of = event_scopes(names.get(plane.name, {}), scopes)
             lines = {ln.name: ln for ln in plane.lines}
             raw[wanted[plane.name]] = (
-                [(s, e, opcode(n)) for s, e, n in _events(lines["XLA Ops"])],
-                [(s, e, opcode(n)) for s, e, n in
-                 (_events(lines["Async XLA Ops"])
-                  if "Async XLA Ops" in lines else ())])
+                _ops(lines["XLA Ops"], of, UNSCOPED),
+                _ops(lines["Async XLA Ops"], of, UNSCOPED)
+                if "Async XLA Ops" in lines else [])
         elif plane.name == "/host:CPU":
             for line in plane.lines:
                 host += [(s, e, n) for s, e, n in _events(line)
@@ -214,11 +240,10 @@ def reduce(path, devices):
     lo, hi = windows[0]
     chips = {}
     for dev, (ops, in_flight) in raw.items():
-        leaf = [(max(s, lo), min(e, hi), op) for s, e, op in ops
+        leaf = [(max(s, lo), min(e, hi), op, sc) for s, e, op, sc in ops
                 if op_class(op) != "control" and e > lo and s < hi]
-        flight = [(max(s, lo), min(e, hi), op) for s, e, op in in_flight
-                  if e > lo and s < hi]
+        flight = [(max(s, lo), min(e, hi), op, sc)
+                  for s, e, op, sc in in_flight if e > lo and s < hi]
         chips[dev] = Chip(leaf, flight)
     spans = [(s, e, n) for s, e, n in host if n in HOST_SPANS]
-    return Trace(chips, (lo, hi), spans)
-
+    return Trace(chips, (lo, hi), spans, scoped=bool(scopes))
