@@ -36,6 +36,7 @@ from .ops import (  # noqa: E402
     allgather,
     allreduce,
     alltoall,
+    alltoallv,
     as_reduce_op,
     barrier,
     bcast,
@@ -100,6 +101,7 @@ __all__ = [
     "allgather",
     "allreduce",
     "alltoall",
+    "alltoallv",
     "barrier",
     "bcast",
     "create_token",

@@ -1,6 +1,7 @@
 from .allgather import allgather
 from .allreduce import allreduce
 from .alltoall import alltoall
+from .alltoallv import alltoallv
 from .barrier import barrier
 from .bcast import bcast
 from .gather import gather
@@ -33,6 +34,7 @@ __all__ = [
     "allgather",
     "allreduce",
     "alltoall",
+    "alltoallv",
     "barrier",
     "bcast",
     "create_token",

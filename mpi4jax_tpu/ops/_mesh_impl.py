@@ -18,6 +18,7 @@ Collective mapping (reference op → XLA collective):
 allreduce       ``lax.psum/pmax/pmin``; generic ops all-gather+reduce
 allgather       ``lax.all_gather(axis=0)``
 alltoall        ``lax.all_to_all(split_axis=0, concat_axis=0)``
+alltoallv       ``lax.ragged_all_to_all`` (padded ``all_to_all`` off TPU)
 bcast           masked ``psum`` (only root contributes)
 reduce          allreduce + select (non-root keeps its input)
 scan            Hillis–Steele ladder of ``lax.ppermute`` (log2 steps)
@@ -167,6 +168,57 @@ def alltoall(x, axis):
 
         return _pc.alltoall(x, axis)
     return lax.all_to_all(x, axis, split_axis=0, concat_axis=0)
+
+
+def alltoallv(x, send_counts, send_displs, recv_counts, recv_displs,
+              out_rows: int, axis, *, ragged=None):
+    """MPI_Alltoallv over ``axis``: rows ``send_displs[j] +
+    [0, send_counts[j])`` of ``x`` land at rows ``recv_displs[me] + [0,
+    ...)`` of rank ``j``'s ``(out_rows, ...)`` output; other rows are zero.
+
+    ``ragged`` (default: on TPU) takes ``lax.ragged_all_to_all``;
+    otherwise every chunk is padded to ``x``'s rows for one
+    ``lax.all_to_all`` and placed by mask, which moves the same rows to
+    the same places (XLA:CPU has no ragged all-to-all).
+    """
+    size = _size(axis)
+    for name, v in (("send_counts", send_counts),
+                    ("send_displs", send_displs),
+                    ("recv_counts", recv_counts),
+                    ("recv_displs", recv_displs)):
+        if v.shape != (size,):
+            raise ValueError(
+                f"alltoallv {name} must have shape ({size},) (one entry per "
+                f"rank), got {v.shape}")
+    if ragged is None:
+        from .flash import target_platform
+
+        ragged = target_platform() == "tpu"
+    x, send_counts, send_displs, recv_counts, recv_displs = (
+        as_varying(v, axis) for v in (x, send_counts, send_displs,
+                                      recv_counts, recv_displs))
+    out = jnp.zeros((out_rows,) + x.shape[1:], x.dtype)
+    if ragged:
+        # where each receiver puts this rank's chunk: its recv_displs[me]
+        dest_displs = lax.all_to_all(recv_displs, axis, 0, 0, tiled=True)
+        return lax.ragged_all_to_all(
+            x, as_varying(out, axis), send_displs, send_counts, dest_displs,
+            recv_counts, axis_name=axis)
+    n = x.shape[0]
+    i = jnp.arange(n)
+    rows = jnp.clip(send_displs[:, None] + i, 0, n - 1)
+    keep = (i < send_counts[:, None]).reshape((size, n) + (1,) * (x.ndim - 1))
+    chunks = jnp.where(keep, x[rows], jnp.zeros((), x.dtype))
+    got = lax.all_to_all(chunks, axis, 0, 0)  # row i: rank i's chunk to me
+    # how many rows each rank sent here: its send_counts[me]
+    sent = lax.all_to_all(send_counts, axis, 0, 0, tiled=True)
+    r = jnp.arange(out_rows)
+    for src in range(size):
+        k = r - recv_displs[src]
+        hit = ((k >= 0) & (k < sent[src])).reshape(
+            (out_rows,) + (1,) * (x.ndim - 1))
+        out = jnp.where(hit, got[src][jnp.clip(k, 0, n - 1)], out)
+    return out
 
 
 def bcast(x, root: int, axis):

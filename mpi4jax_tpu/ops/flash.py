@@ -193,11 +193,13 @@ def _flash_fwd_block(q, k, v, q_off, k_off, *, scale, causal,
                      block_q, block_k, interpret):
     """Partial flash attention of local q against one k/v ring block.
 
-    q: (BH, Tq, D); k, v: (BH, Tk, D); offsets are traced global starts.
-    Returns float32 (o_unnormalized (BH,Tq,D), m (BH,Tq,1), l (BH,Tq,1)).
+    q, k: (BH, Tq|Tk, D); v: (BH, Tk, Dv), where Dv may differ from D (as
+    in latent attention); offsets are traced global starts.  Returns
+    float32 (o_unnormalized (BH,Tq,Dv), m (BH,Tq,1), l (BH,Tq,1)).
     """
     bh, tq, d = q.shape
     tk = k.shape[1]
+    dv = v.shape[-1]
     nq, nk = tq // block_q, tk // block_k
     offs = jnp.stack([q_off, k_off]).astype(jnp.int32)
 
@@ -208,17 +210,17 @@ def _flash_fwd_block(q, k, v, q_off, k_off, *, scale, causal,
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda b, i, j, *_: (b, i, 0)),
             pl.BlockSpec((None, block_k, d), kv_idx),
-            pl.BlockSpec((None, block_k, d), kv_idx),
+            pl.BlockSpec((None, block_k, dv), kv_idx),
         ],
         out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda b, i, j, *_: (b, i, 0)),
+            pl.BlockSpec((None, block_q, dv), lambda b, i, j, *_: (b, i, 0)),
             pl.BlockSpec((None, block_q, 1), lambda b, i, j, *_: (b, i, 0)),
             pl.BlockSpec((None, block_q, 1), lambda b, i, j, *_: (b, i, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
     )
     return pl.pallas_call(
@@ -226,7 +228,7 @@ def _flash_fwd_block(q, k, v, q_off, k_off, *, scale, causal,
                 block_q=block_q, block_k=block_k),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tq, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, tq, dv), jnp.float32),
             jax.ShapeDtypeStruct((bh, tq, 1), jnp.float32),
             jax.ShapeDtypeStruct((bh, tq, 1), jnp.float32),
         ],
@@ -467,6 +469,29 @@ def _ring_flash_bwd(axis, causal, scale, block_q, block_k, interpret,
 
 
 _ring_flash.defvjp(_ring_flash_fwd, _ring_flash_bwd)
+
+
+def flash_attention(q, k, v, *, causal=False, block_q=1024, block_k=1024,
+                    interpret=None):
+    """Attention of local ``q`` over local ``k``/``v`` with the flash
+    forward kernel (one block of the ring, no exchange, no gradient).
+
+    q, k: ``(B, T, H, D)``; v: ``(B, T, H, Dv)``, where ``Dv`` may differ
+    from ``D`` (latent attention: q.k over nope and rope parts, v of the
+    value head).  The softmax scale ``1/sqrt(D)`` is folded into q as in
+    :func:`ring_flash_attention`.  Returns ``(B, T, H, Dv)`` in q's dtype.
+    """
+    b, t, h, _ = q.shape
+    scale = float(1.0 / (q.shape[-1] ** 0.5))
+    if interpret is None:
+        interpret = _interpret_default()
+    q = (q * jnp.asarray(scale, q.dtype)).astype(q.dtype)
+    o, _, l = _flash_fwd_block(
+        _to_bhtd(q), _to_bhtd(k), _to_bhtd(v), jnp.int32(0), jnp.int32(0),
+        scale=1.0, causal=bool(causal), block_q=pick_block(t, block_q),
+        block_k=pick_block(t, block_k), interpret=bool(interpret))
+    out = (o / jnp.where(l == 0.0, 1.0, l)).astype(q.dtype)
+    return _from_bhtd(out, b, h)
 
 
 def ring_flash_attention(q, k, v, *, axis, causal=False, scale=None,

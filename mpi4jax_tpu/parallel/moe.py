@@ -12,6 +12,16 @@ observation that routed activations tolerate low-precision transport,
 arXiv:2506.17615) and ``algo=`` to force a schedule
 (``"qalltoall"``/``"halltoall"``/``"hqalltoall"``) on a world comm.
 
+A second layer, :func:`expert_parallel_ffn`, is the dropless form of
+DeepSeek-V3-style models: each rank holds a block of ``n_experts / size``
+SwiGLU experts, every token goes to its top-k experts (router
+:func:`route_topk_sigmoid`), and the rows travel in exactly the numbers
+routed, over :func:`mpi4jax_tpu.ops.alltoallv`.  Received rows run
+through the experts as grouped matmuls (JAX's megablox ``gmm`` Pallas
+kernel, whose grid covers only the rows present), and return the same
+way.  Nothing is dropped, so the receive buffer is sized for the worst
+case (every token's k rows to one rank).
+
 Composes with the other axes exactly like :mod:`.tp`/:mod:`.ulysses`:
 ``comm`` names the expert axis (a ``MeshComm`` sub-axis or a world
 comm), so dp/tp/pp can own the remaining axes.  Capacity-based binning
@@ -143,3 +153,120 @@ def init_moe_params(key, d_model: int, d_ff: int, n_experts: int,
         "w_out": w_out[expert_rank],
         "b_out": jnp.zeros((d_model,), dtype),
     }
+
+
+# -- dropless top-k expert parallelism ---------------------------------------
+
+def route_topk_sigmoid(x, w_gate, bias, top_k: int, scale: float = 1.0):
+    """DeepSeek-V3 ``noaux_tc`` routing with one group: sigmoid scores in
+    float32, the top-k of ``scores + bias`` chosen (the bias steers
+    selection only), the chosen scores normalised to sum 1 and scaled.
+
+    Returns ``(idx, weight)``, each ``(tokens, top_k)``: expert ids
+    (int32) and float32 weights.
+    """
+    logits = jnp.dot(x.astype(jnp.float32), w_gate.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
+    return idx.astype(jnp.int32), w
+
+
+def _tile(dim: int, preferred: int) -> int:
+    """Largest multiple of 128 dividing ``dim`` up to ``preferred``, else
+    the whole dimension (a block equal to the array's dimension is
+    always legal)."""
+    for t in range(min(preferred, dim) // 128 * 128, 0, -128):
+        if dim % t == 0:
+            return t
+    return dim
+
+
+def grouped_matmul(lhs, rhs, group_sizes, *, n_blocks: int = 1):
+    """Rows of ``lhs`` times their group's matrix: ``group_sizes`` (int32,
+    ``n_blocks * rhs.shape[0]``) gives consecutive row groups, group ``g``
+    using ``rhs[g % rhs.shape[0]]``.  The ``n_blocks`` blocks of groups
+    (one per sending rank after an exchange) run as chained megablox
+    ``gmm`` calls with ``group_offset``, each writing its groups' rows
+    into one zero-filled output.  Output in ``lhs``'s dtype, accumulated
+    in float32."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from ..ops.flash import _interpret_default
+
+    interpret = _interpret_default()
+    m, k = lhs.shape
+    n = rhs.shape[-1]
+    # tiles within the kernel's scoped VMEM on a v5e: 512 rows, and an
+    # rhs block of at most 2**20 elements (double-buffered with the lhs,
+    # output and accumulator blocks)
+    tm = m if m < 512 else next(t for t in range(512, 0, -8) if m % t == 0)
+    tk = _tile(k, 512) if k > 1536 else k
+    tiling = (tm, tk, _tile(n, max(128, 2 ** 20 // tk)))
+    per = rhs.shape[0]
+    # a zero-filled output to write into: without one, the first call
+    # zeroes every row outside its groups in a pass of its own
+    out = jnp.zeros((m, n), lhs.dtype)
+    for b in range(n_blocks):
+        out = gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+                  tiling=tiling, group_offset=jnp.int32(b * per),
+                  existing_out=out, interpret=interpret)
+    return out
+
+
+def expert_parallel_ffn(x, idx, weight, w_gate_up, w_down, *,
+                        n_experts: int, comm=None):
+    """Dropless expert-parallel SwiGLU experts over ``comm``.
+
+    ``x`` ``(tokens, d)``: this rank's tokens; ``idx``/``weight``
+    ``(tokens, k)``: their experts (global ids in ``[0, n_experts)``) and
+    combine weights, from :func:`route_topk_sigmoid`.  This rank holds
+    experts ``[rank * e, (rank + 1) * e)``, ``e = n_experts / size``:
+    ``w_gate_up`` ``(e, d, 2 f)`` (gate then up) and ``w_down`` ``(e, f,
+    d)``.  Each expert computes ``down(silu(gate x) * up x)``.
+
+    Returns ``(y, counts)``: ``y`` ``(tokens, d)`` in ``x``'s dtype, the
+    weighted sum of each token's k expert outputs, and ``counts``
+    ``(n_experts,)`` int32, the rows this rank routed to each expert.
+    """
+    comm = _resolve(comm)
+    size = comm.size()
+    t, d = x.shape
+    top_k = idx.shape[-1]
+    local = w_gate_up.shape[0]
+    if local * size != n_experts:
+        raise ValueError(f"{local} experts per rank on {size} ranks do not "
+                         f"make {n_experts}")
+    f = w_down.shape[1]
+    with jax.named_scope("moe_route"):
+        flat = idx.reshape(-1)
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        counts = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
+        rows = x[order // top_k]
+    with jax.named_scope("moe_dispatch"):
+        # counts[src, dest rank, local expert] of every rank
+        every = ops.allgather(counts, comm=comm).reshape(size, size, local)
+        mine = jnp.take(every, comm.rank(), axis=1)  # (src, local expert)
+        send = jnp.sum(jnp.take(every, comm.rank(), axis=0), axis=-1)
+        recv = jnp.sum(mine, axis=-1)
+        # rows from each rank in rank order, each block sorted by expert
+        got = ops.alltoallv(rows, send, recv, out_rows=size * t * top_k,
+                            comm=comm)
+    with jax.named_scope("moe_gmm"):
+        groups = mine.reshape(-1)
+        h = grouped_matmul(got, w_gate_up, groups, n_blocks=size)
+        # each half converted on its own, so that XLA fuses the converts
+        # into the SwiGLU pass instead of writing h out in float32
+        act = (jax.nn.silu(h[:, :f].astype(jnp.float32))
+               * h[:, f:].astype(jnp.float32)).astype(x.dtype)
+        out = grouped_matmul(act, w_down, groups, n_blocks=size)
+    with jax.named_scope("moe_combine"):
+        back = ops.alltoallv(out, recv, send, out_rows=t * top_k, comm=comm)
+    with jax.named_scope("moe_route"):
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(t * top_k, dtype=jnp.int32))
+        back = back[inverse].reshape(t, top_k, d).astype(jnp.float32)
+        y = jnp.einsum("tkd,tk->td", back, weight).astype(x.dtype)
+    return y, counts

@@ -80,3 +80,26 @@ def test_pick_block():
     assert pick_block(96, 128) == 96
     assert pick_block(48, 32) == 24
     assert pick_block(7, 128) == 7
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_flash_attention_value_head_differs(causal):
+    """Latent attention's shapes: q.k over a head of 24 (nope + rope),
+    values of 16, against plain softmax attention."""
+    from mpi4jax_tpu.ops.flash import flash_attention
+
+    b, t, h, dk, dv = 2, 32, 3, 24, 16
+    rng = np.random.RandomState(3)
+    q, k = (jnp.asarray(rng.randn(b, t, h, dk).astype(np.float32) * 0.5)
+            for _ in range(2))
+    v = jnp.asarray(rng.randn(b, t, h, dv).astype(np.float32))
+    out = jax.jit(lambda a, c, e: flash_attention(
+        a, c, e, causal=causal, block_q=16, block_k=8, interpret=True))(
+            q, k, v)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(dk)
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+    assert out.shape == (b, t, h, dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)

@@ -181,3 +181,42 @@ def test_ici_kernel_compiles_on_four_chips(four_chips, kernel):
     spec = jax.ShapeDtypeStruct(shape, jnp.float32,
                                 sharding=NamedSharding(four_chips, P("x")))
     _assert_kernel(fn.lower(spec).compile())
+
+
+def test_expert_parallel_moe_compiles_on_four_chips(four_chips):
+    """The dropless EP4 MoE layer at a small width: the dispatch and the
+    combine are ``ragged-all-to-all`` (no padded all-to-all of expert
+    rows), the experts megablox ``gmm`` Mosaic kernels."""
+    from mpi4jax_tpu.parallel.mesh import MeshComm
+    from mpi4jax_tpu.parallel.moe import (expert_parallel_ffn,
+                                          route_topk_sigmoid)
+
+    t, d, f, experts = 512, 256, 256, 16
+
+    def layer(x, w_gate, bias, w_gate_up, w_down):
+        idx, w = route_topk_sigmoid(x, w_gate, bias, 6, 2.446)
+        return expert_parallel_ffn(x, idx, w, w_gate_up, w_down,
+                                   n_experts=experts,
+                                   comm=MeshComm("x", mesh=four_chips))[0]
+
+    fn = jax.jit(jax.shard_map(
+        layer, mesh=four_chips, in_specs=(P("x"), P(), P(), P("x"), P("x")),
+        out_specs=P("x"), check_vma=False))
+
+    def spec(shape, dtype, p):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(four_chips, p))
+
+    hlo = fn.lower(spec((4 * t, d), jnp.bfloat16, P("x")),
+                   spec((d, experts), jnp.bfloat16, P()),
+                   spec((experts,), jnp.float32, P()),
+                   spec((experts, d, 2 * f), jnp.bfloat16, P("x")),
+                   spec((experts, f, d), jnp.bfloat16, P("x"))
+                   ).compile().as_text()
+    assert len(re.findall(r" ragged-all-to-all\(", hlo)) == 2
+    # the only all-to-alls left move one offset per rank
+    for line in re.findall(r".* all-to-all\(.*", hlo):
+        assert re.search(r"= s32\[4(,1)*\]", line), line
+    gmm = [ln for ln in hlo.splitlines()
+           if "tpu_custom_call" in ln and "moe_gmm" in ln]
+    assert len(gmm) == 8, len(gmm)  # gate-up and down, one per source rank
