@@ -19,8 +19,10 @@ SwiGLU experts, every token goes to its top-k experts (router
 routed, over :func:`mpi4jax_tpu.ops.alltoallv`.  Received rows run
 through the experts as grouped matmuls (JAX's megablox ``gmm`` Pallas
 kernel, whose grid covers only the rows present), and return the same
-way.  Nothing is dropped, so the receive buffer is sized for the worst
-case (every token's k rows to one rank).
+way.  Nothing is dropped: the receive buffer takes the smallest of a
+short static ladder of row capacities (:func:`receive_capacities`) that
+holds what was routed, chosen per call from the all-gathered counts, and
+the ladder ends at the worst case (every token's k rows to one rank).
 
 Composes with the other axes exactly like :mod:`.tp`/:mod:`.ulysses`:
 ``comm`` names the expert axis (a ``MeshComm`` sub-axis or a world
@@ -32,10 +34,12 @@ is the zero vector — the standard Switch capacity discipline).
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import ops
 from .mesh import get_default_comm
@@ -184,6 +188,12 @@ def _tile(dim: int, preferred: int) -> int:
     return dim
 
 
+# grouped_matmul's row tile; receive capacities below the worst case are
+# multiples of it, so that the expert kernels tile the received rows as
+# they would in the worst-case buffer
+_ROW_TILE = 512
+
+
 def grouped_matmul(lhs, rhs, group_sizes, *, n_blocks: int = 1):
     """Rows of ``lhs`` times their group's matrix: ``group_sizes`` (int32,
     ``n_blocks * rhs.shape[0]``) gives consecutive row groups, group ``g``
@@ -202,7 +212,8 @@ def grouped_matmul(lhs, rhs, group_sizes, *, n_blocks: int = 1):
     # tiles within the kernel's scoped VMEM on a v5e: 512 rows, and an
     # rhs block of at most 2**20 elements (double-buffered with the lhs,
     # output and accumulator blocks)
-    tm = m if m < 512 else next(t for t in range(512, 0, -8) if m % t == 0)
+    tm = (m if m < _ROW_TILE
+          else next(t for t in range(_ROW_TILE, 0, -8) if m % t == 0))
     tk = _tile(k, 512) if k > 1536 else k
     tiling = (tm, tk, _tile(n, max(128, 2 ** 20 // tk)))
     per = rhs.shape[0]
@@ -214,6 +225,48 @@ def grouped_matmul(lhs, rhs, group_sizes, *, n_blocks: int = 1):
                   tiling=tiling, group_offset=jnp.int32(b * per),
                   existing_out=out, interpret=interpret)
     return out
+
+
+# receive capacities below the worst case, in units of the rows each rank
+# routes (t * top_k), which is what a rank receives on average
+_RUNGS = (1.25, 1.5, 2.0)
+
+
+def receive_capacities(t: int, top_k: int, size: int):
+    """The static row capacities of :func:`expert_parallel_ffn`'s receive
+    buffer for ``t`` tokens a rank, routed top-``top_k`` over ``size``
+    ranks, smallest first: ``_RUNGS`` of ``t * top_k`` rounded up to
+    multiples of 512, those below the worst case, then the worst case
+    ``size * t * top_k`` (every row of every rank to one rank)."""
+    rows = t * top_k
+    worst = size * rows
+    below = {-(-math.ceil(share * rows) // _ROW_TILE) * _ROW_TILE
+             for share in _RUNGS}
+    return tuple(sorted(c for c in below if c < worst)) + (worst,)
+
+
+def receive_rung(every, capacities):
+    """Index of the smallest of ``capacities`` that holds the most rows
+    any rank receives, from ``every`` ``(size, size, local)``: rows each
+    source rank routed to each destination rank's local experts.  Over
+    ``jnp``, so on the device and on the host alike."""
+    most = jnp.max(jnp.sum(every, axis=(0, 2)))
+    return jnp.searchsorted(jnp.asarray(capacities), most).astype(jnp.int32)
+
+
+def receive_rungs(counts, top_k: int):
+    """The rung of :func:`receive_capacities` each layer took, from
+    ``counts`` ``(size, layers, n_experts)``: the rows each rank routed to
+    each expert in each layer (the per-layer ``counts`` of
+    :func:`expert_parallel_ffn`, stacked by rank).  Returns ``(layers,)``
+    int32; the last rung is the worst case."""
+    counts = np.asarray(counts)
+    size, layers, _ = counts.shape
+    t = int(counts[0, 0].sum()) // top_k
+    caps = receive_capacities(t, top_k, size)
+    return np.array([int(receive_rung(counts[:, layer].reshape(size, size,
+                                                               -1), caps))
+                     for layer in range(layers)], np.int32)
 
 
 def expert_parallel_ffn(x, idx, weight, w_gate_up, w_down, *,
@@ -245,25 +298,37 @@ def expert_parallel_ffn(x, idx, weight, w_gate_up, w_down, *,
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)
         counts = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
         rows = x[order // top_k]
+    capacities = receive_capacities(t, top_k, size)
     with jax.named_scope("moe_dispatch"):
         # counts[src, dest rank, local expert] of every rank
         every = ops.allgather(counts, comm=comm).reshape(size, size, local)
         mine = jnp.take(every, comm.rank(), axis=1)  # (src, local expert)
         send = jnp.sum(jnp.take(every, comm.rank(), axis=0), axis=-1)
         recv = jnp.sum(mine, axis=-1)
-        # rows from each rank in rank order, each block sorted by expert
-        got = ops.alltoallv(rows, send, recv, out_rows=size * t * top_k,
-                            comm=comm)
-    with jax.named_scope("moe_gmm"):
-        groups = mine.reshape(-1)
-        h = grouped_matmul(got, w_gate_up, groups, n_blocks=size)
-        # each half converted on its own, so that XLA fuses the converts
-        # into the SwiGLU pass instead of writing h out in float32
-        act = (jax.nn.silu(h[:, :f].astype(jnp.float32))
-               * h[:, f:].astype(jnp.float32)).astype(x.dtype)
-        out = grouped_matmul(act, w_down, groups, n_blocks=size)
-    with jax.named_scope("moe_combine"):
-        back = ops.alltoallv(out, recv, send, out_rows=t * top_k, comm=comm)
+        # every rank holds every rank's counts, so all take the same rung
+        rung = receive_rung(every, capacities)
+    groups = mine.reshape(-1)
+
+    def experts(capacity, rows):
+        """Dispatch into ``capacity`` rows, the experts, the combine."""
+        with jax.named_scope("moe_dispatch"):
+            # rows from each rank in rank order, each block sorted by expert
+            got = ops.alltoallv(rows, send, recv, out_rows=capacity,
+                                comm=comm)
+        with jax.named_scope("moe_gmm"):
+            h = grouped_matmul(got, w_gate_up, groups, n_blocks=size)
+            # each half converted on its own, so that XLA fuses the
+            # converts into the SwiGLU pass instead of writing h out in
+            # float32
+            act = (jax.nn.silu(h[:, :f].astype(jnp.float32))
+                   * h[:, f:].astype(jnp.float32)).astype(x.dtype)
+            out = grouped_matmul(act, w_down, groups, n_blocks=size)
+        with jax.named_scope("moe_combine"):
+            return ops.alltoallv(out, recv, send, out_rows=t * top_k,
+                                 comm=comm)
+
+    back = jax.lax.switch(
+        rung, [functools.partial(experts, c) for c in capacities], rows)
     with jax.named_scope("moe_route"):
         inverse = jnp.zeros_like(order).at[order].set(
             jnp.arange(t * top_k, dtype=jnp.int32))

@@ -21,7 +21,9 @@ import pytest
 from jax.sharding import Mesh
 
 from mpi4jax_tpu.models import moonlight
-from mpi4jax_tpu.parallel.moe import route_topk_sigmoid
+from mpi4jax_tpu.parallel import moe
+from mpi4jax_tpu.parallel.moe import (receive_capacities, receive_rungs,
+                                      route_topk_sigmoid)
 
 REPO = pathlib.Path(__file__).resolve().parents[2]
 SEED = 11
@@ -142,7 +144,64 @@ def test_skewed_router_drops_nothing(ref, monkeypatch):
     got, counts = _run(hf, tokens, bias=bias)
     per_rank = counts.sum(axis=(0, 1)).reshape(4, 8).sum(axis=1)
     np.testing.assert_array_equal(per_rank, [0, 0, 2 * 4 * 64 * 6, 0])
+    # both layers took the last rung of the ladder, the worst case
+    ladder = receive_capacities(64, 6, 4)
+    assert len(ladder) > 1
+    np.testing.assert_array_equal(receive_rungs(counts, 6),
+                                  [len(ladder) - 1] * 2)
     _assert_close(ref, got, _reference(ref, hf, tokens, bias, monkeypatch))
+
+
+def test_smaller_rung_is_bit_identical_to_the_worst_case(monkeypatch):
+    """Unbiased routing takes a rung below the worst case in every layer,
+    and the answer is bit for bit that of the layer whose ladder is the
+    worst case alone: the same rows, in the same order and tiling."""
+    tokens = _tokens()
+    got, counts = _run_four(tokens)
+    rungs = receive_rungs(counts, 6)
+    assert (rungs < len(receive_capacities(64, 6, 4)) - 1).all(), rungs
+    monkeypatch.setattr(moe, "receive_capacities",
+                        lambda t, top_k, size: (size * t * top_k,))
+    worst, worst_counts = _run(HF, tokens)
+    np.testing.assert_array_equal(worst_counts, counts)
+    for name in ("hidden", "logits"):
+        np.testing.assert_array_equal(got[name], worst[name])
+
+
+def test_receive_rungs_agree_with_the_rungs_taken(monkeypatch):
+    """``receive_rungs`` on the prefill's counts gives, for each layer,
+    the rung that every rank's layer chose."""
+    rung = moe.receive_rung
+
+    def recorded(every, capacities):
+        r = rung(every, capacities)
+        jax.debug.callback(
+            lambda e, i: taken.append((np.asarray(e).copy(), int(i))),
+            every, r)
+        return r
+
+    monkeypatch.setattr(moe, "receive_rung", recorded)
+    hf = dict(HF, n_routed_experts=32)
+    bias = np.zeros(32, np.float32)
+    bias[16:24] = 10.0  # the second token batch's layers: all to rank 2
+    runs = []
+    for tokens, b in ((_tokens(), None), (_tokens(1), bias)):
+        taken = []
+        _, counts = _run(hf, tokens, bias=b)
+        jax.effects_barrier()
+        runs.append((counts, taken))
+    monkeypatch.undo()
+    rungs = {}
+    for counts, taken in runs:
+        assert len(taken) == 4 * 2  # every rank, both layers
+        for layer, r in enumerate(receive_rungs(counts, 6)):
+            every = counts[:, layer].reshape(4, 4, 8)
+            chose = [i for e, i in taken if np.array_equal(e, every)]
+            assert chose == [r] * 4, (layer, chose, r)
+            rungs[r] = rungs.get(r, 0) + 1
+    # both a rung below the worst case and the worst case were taken
+    last = len(receive_capacities(64, 6, 4)) - 1
+    assert min(rungs) < last and max(rungs) == last, rungs
 
 
 def test_selection_by_biased_score_weighting_by_score(ref, monkeypatch):

@@ -183,12 +183,31 @@ def test_ici_kernel_compiles_on_four_chips(four_chips, kernel):
     _assert_kernel(fn.lower(spec).compile())
 
 
+def _computations(hlo):
+    """``{name: body lines}`` of every computation in an HLO module's
+    text."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            comps[name].append(line)
+    return comps
+
+
 def test_expert_parallel_moe_compiles_on_four_chips(four_chips):
-    """The dropless EP4 MoE layer at a small width: the dispatch and the
-    combine are ``ragged-all-to-all`` (no padded all-to-all of expert
-    rows), the experts megablox ``gmm`` Mosaic kernels."""
+    """The dropless EP4 MoE layer at a small width: one ``conditional``
+    over the receive capacities, each branch a dispatch and a combine
+    ``ragged-all-to-all`` (no padded all-to-all of expert rows) around
+    the experts' megablox ``gmm`` Mosaic kernels, the last branch at the
+    worst case."""
     from mpi4jax_tpu.parallel.mesh import MeshComm
     from mpi4jax_tpu.parallel.moe import (expert_parallel_ffn,
+                                          receive_capacities,
                                           route_topk_sigmoid)
 
     t, d, f, experts = 512, 256, 256, 16
@@ -213,10 +232,25 @@ def test_expert_parallel_moe_compiles_on_four_chips(four_chips):
                    spec((experts, d, 2 * f), jnp.bfloat16, P("x")),
                    spec((experts, f, d), jnp.bfloat16, P("x"))
                    ).compile().as_text()
-    assert len(re.findall(r" ragged-all-to-all\(", hlo)) == 2
+    capacities = receive_capacities(t, 6, 4)
+    assert len(capacities) == 4 and capacities[-1] == 4 * t * 6
+    (cond,) = re.findall(r".* conditional\(.*", hlo)
+    branches = re.search(r"branch_computations=\{([^}]*)\}", cond).group(1)
+    comps = _computations(hlo)
+    dispatched = []
+    for name in branches.replace("%", "").split(", "):
+        body = comps[name]
+        exchanges = [ln for ln in body if " ragged-all-to-all(" in ln]
+        assert len(exchanges) == 2, exchanges
+        (dispatch,) = [ln for ln in exchanges if "moe_dispatch" in ln]
+        assert [ln for ln in exchanges if "moe_combine" in ln]
+        dispatched.append(int(re.search(r"= bf16\[(\d+),", dispatch)[1]))
+        gmm = [ln for ln in body
+               if "tpu_custom_call" in ln and "moe_gmm" in ln]
+        assert len(gmm) == 8, len(gmm)  # gate-up and down, per source rank
+    assert dispatched == list(capacities)
+    ragged = re.findall(r" ragged-all-to-all\(", hlo)
+    assert len(ragged) == 2 * len(capacities)
     # the only all-to-alls left move one offset per rank
     for line in re.findall(r".* all-to-all\(.*", hlo):
         assert re.search(r"= s32\[4(,1)*\]", line), line
-    gmm = [ln for ln in hlo.splitlines()
-           if "tpu_custom_call" in ln and "moe_gmm" in ln]
-    assert len(gmm) == 8, len(gmm)  # gate-up and down, one per source rank
